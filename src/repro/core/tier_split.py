@@ -112,6 +112,31 @@ def make_extract_fn(model: Model, plan: TierPlan) -> Callable:
     return extract
 
 
+def make_extract_executor(model: Model, frozen: Any, plan: TierPlan) -> Callable:
+    """The storage server's live executor ``fn(payload, split, cos_batch)``
+    over the frozen prefix of ``plan``.
+
+    The server grants the simulated Eq. 4 COS batch, which need not
+    divide an object; the executor runs the largest microbatch that
+    divides it and does not exceed the grant, with one jitted extract per
+    microbatch size (``fn.compiled``, keyed by that size)."""
+    compiled = {}
+
+    def execute(payload, split, cos_batch):
+        if split != plan.split:
+            raise ValueError(f"executor holds the prefix to block "
+                             f"{plan.split}, request asks for {split}")
+        n = next(iter(payload.values())).shape[0]
+        mb = largest_divisor_leq(n, cos_batch)
+        if mb not in compiled:
+            compiled[mb] = jax.jit(make_extract_fn(model, TierPlan(
+                plan.split, mb, plan.compress, plan.decision)))
+        return compiled[mb](frozen, payload)
+
+    execute.compiled = compiled
+    return execute
+
+
 def make_tune_loss_fn(model: Model, plan: TierPlan) -> Callable:
     def tune_loss(trainable, acts, batch):
         if plan.compress:

@@ -33,9 +33,9 @@ def pipeline_stages(
     Usage (mesh has an axis named ``axis`` of size n_stages):
 
         body = pipeline_stages(stage_fn, S, M)
-        y = repro.compat.shard_map(body, mesh=mesh,
-                                   in_specs=(P(axis), P(axis)), out_specs=P(),
-                                   check_vma=False)(stage_params, micro_x)
+        y = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(axis), P(axis)), out_specs=P(),
+                          check_vma=False)(stage_params, micro_x)
 
     ``stage_params`` leaves have leading dim n_stages (one slice per
     stage); ``micro_x`` has leading dim n_micro, sharded contiguously over

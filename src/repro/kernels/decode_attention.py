@@ -9,6 +9,10 @@ is why GQA decode wants the group dim collapsed into the matmul.
 
 The valid-length mask comes from a scalar operand (SMEM) so the same
 compiled kernel serves any cache fill level.
+
+Layout: the wrapper puts KV heads ahead of the cache sequence, (B, Hkv,
+S, hd), so a cache block's last two dims are (s_block, hd) — multiples
+of the (8, 128) tile — rather than (1, hd).
 """
 from __future__ import annotations
 
@@ -41,8 +45,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(s_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (rep, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (sb, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                 # (sb, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                           # (rep, sb)
@@ -78,7 +82,7 @@ def decode_attention_pallas(
     *,
     softcap: Optional[float] = None,
     s_block: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, hq, hd = q.shape
     _, s, hkv, _ = k_cache.shape
@@ -90,6 +94,8 @@ def decode_attention_pallas(
         k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
 
     qg = q.reshape(b, hkv, rep, hd)
+    kt = k_cache.transpose(0, 2, 1, 3)                       # (B, Hkv, S, hd)
+    vt = v_cache.transpose(0, 2, 1, 3)
     n_s = s_pad // s_block
     grid = (b, hkv, n_s)
     scale = 1.0 / math.sqrt(hd)
@@ -104,8 +110,8 @@ def decode_attention_pallas(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, rep, hd), lambda bi, hi, si: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, s_block, 1, hd), lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, s_block, 1, hd), lambda bi, hi, si: (bi, si, hi, 0)),
+            pl.BlockSpec((1, 1, s_block, hd), lambda bi, hi, si: (bi, hi, si, 0)),
+            pl.BlockSpec((1, 1, s_block, hd), lambda bi, hi, si: (bi, hi, si, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd), lambda bi, hi, si: (bi, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, hd), q.dtype),
@@ -115,5 +121,5 @@ def decode_attention_pallas(
             pltpu.VMEM((rep, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(length_arr, qg, k_cache, v_cache)
+    )(length_arr, qg, kt, vt)
     return out.reshape(b, hq, hd)

@@ -101,7 +101,7 @@ def flash_attention_pallas(
     softcap: Optional[float] = None,
     q_block: int = 256,
     kv_block: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, s, h, hd = q.shape
     q_block = min(q_block, s)

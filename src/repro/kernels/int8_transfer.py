@@ -8,6 +8,11 @@ dequantize on the compute tier: exactly
 bottleneck link — (1 + 4/128)/2 = 0.515625x for bf16 with the default
 128 tile. Tiles are (rows x 128) — one scale per VREG lane group, so
 the abs-max reduction and the scaled cast both vectorize cleanly.
+
+Compiled (``interpret=False``, the TPU path) the feature width must be a
+multiple of 128 lanes: the in-kernel (rows, D/128, 128) reshape is the
+only form Mosaic lays out. Interpret mode keeps the ``gcd(d, tile)``
+clamp for narrow widths.
 """
 from __future__ import annotations
 
@@ -17,6 +22,17 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+LANES = 128
+
+
+def _check_lanes(d: int, tile: int, interpret: bool) -> None:
+    if not interpret and (d % LANES or tile % LANES):
+        raise ValueError(
+            f"the compiled int8 kernels need the feature width and tile to be "
+            f"multiples of {LANES} lanes, got d={d}, tile={tile}; pad the "
+            f"width or use interpret mode off-TPU")
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, tile: int):
@@ -39,9 +55,10 @@ def _dequant_kernel(q_ref, s_ref, x_ref, *, tile: int):
 
 @functools.partial(jax.jit, static_argnames=("tile", "row_block", "interpret"))
 def quantize_int8_pallas(x: jnp.ndarray, *, tile: int = 128,
-                         row_block: int = 256, interpret: bool = True):
+                         row_block: int = 256, interpret: bool = False):
     *lead, d = x.shape
     tile = math.gcd(d, tile)
+    _check_lanes(d, tile, interpret)
     rows = int(math.prod(lead)) if lead else 1
     xf = x.reshape(rows, d)
     rb = min(row_block, rows)
@@ -71,9 +88,10 @@ def quantize_int8_pallas(x: jnp.ndarray, *, tile: int = 128,
 @functools.partial(jax.jit, static_argnames=("dtype", "row_block", "interpret"))
 def dequantize_int8_pallas(q: jnp.ndarray, scales: jnp.ndarray, *,
                            dtype=jnp.bfloat16,
-                           row_block: int = 256, interpret: bool = True):
+                           row_block: int = 256, interpret: bool = False):
     *lead, d = q.shape
     tile = d // scales.shape[-1]
+    _check_lanes(d, tile, interpret)
     rows = int(math.prod(lead)) if lead else 1
     qf = q.reshape(rows, d)
     sf = scales.reshape(rows, d // tile)

@@ -1,22 +1,25 @@
-"""Jit'd public wrappers for the Pallas kernels, with backend dispatch.
+"""Public wrappers for the Pallas kernels, with backend dispatch.
 
-``use_pallas(True)`` routes to the Pallas TPU kernels (the TARGET
-implementation, validated in interpret mode on CPU); the default routes to
-the pure-XLA references so every higher layer runs unchanged on any
-backend. The dry-run lowers the XLA path; the kernels are the TPU
-deployment path (DESIGN.md §3).
+The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``) follows
+the platform: on TPU it runs the compiled Pallas kernels, elsewhere the
+pure-XLA references. Flash, decode and SSD attention sit off the model
+path; ``use_pallas(True)`` routes them to their Pallas kernels, and
+``use_pallas(True, interpret=True)`` runs those kernels in the Pallas
+interpreter off-TPU (tests only). Every wrapper reads the switch when it
+is called, so a toggle takes effect on the next call.
 """
 from __future__ import annotations
-
-import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import decode_attention as dk
+from repro.kernels import flash_attention as fk
+from repro.kernels import int8_transfer as ik
 from repro.kernels import ref
+from repro.kernels import ssd_scan as sk
 
-_STATE = {"pallas": False, "interpret": True}
+_STATE = {"pallas": False, "interpret": False}
 
 # ---------------------------------------------------------------------------
 # The authoritative int8 wire-compression ratio.
@@ -53,7 +56,17 @@ def compression_ratio(dtype=jnp.bfloat16, tile: int = WIRE_TILE) -> float:
 INT8_WIRE_RATIO = compression_ratio(jnp.bfloat16, WIRE_TILE)
 
 
-def use_pallas(enable: bool = True, interpret: bool = True) -> None:
+def on_tpu() -> bool:
+    """Whether this process computes on a TPU (the compiled-kernel path)."""
+    return jax.default_backend() == "tpu"
+
+
+def use_pallas(enable: bool = True, *, interpret: bool = False) -> None:
+    """Route flash, decode and SSD attention to their Pallas kernels.
+    ``interpret`` runs them in the Pallas interpreter; it is refused on
+    TPU, where the kernels compile."""
+    if interpret and on_tpu():
+        raise ValueError("Pallas interpret mode is for testing off-TPU")
     _STATE["pallas"] = enable
     _STATE["interpret"] = interpret
 
@@ -62,56 +75,51 @@ def pallas_enabled() -> bool:
     return _STATE["pallas"]
 
 
+_ref_flash = jax.jit(ref.flash_attention,
+                     static_argnames=("causal", "window", "softcap"))
+_ref_decode = jax.jit(ref.decode_attention, static_argnames=("softcap",))
+_ref_ssd = jax.jit(ref.ssd_reference)
+_ref_quantize = jax.jit(ref.quantize_int8, static_argnames=("tile",))
+_ref_dequantize = jax.jit(ref.dequantize_int8, static_argnames=("dtype",))
+
+
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap"))
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None):
     if _STATE["pallas"]:
-        from repro.kernels import flash_attention as fk
-
         return fk.flash_attention_pallas(
             q, k, v, causal=causal, window=window, softcap=softcap,
             interpret=_STATE["interpret"],
         )
-    return ref.flash_attention(q, k, v, causal=causal, window=window, softcap=softcap)
+    return _ref_flash(q, k, v, causal=causal, window=window, softcap=softcap)
 
 
-@functools.partial(jax.jit, static_argnames=("softcap",))
 def decode_attention(q, k_cache, v_cache, length, *, softcap=None):
     if _STATE["pallas"]:
-        from repro.kernels import decode_attention as dk
-
         return dk.decode_attention_pallas(
             q, k_cache, v_cache, length, softcap=softcap,
             interpret=_STATE["interpret"],
         )
-    return ref.decode_attention(q, k_cache, v_cache, length, softcap=softcap)
+    return _ref_decode(q, k_cache, v_cache, length, softcap=softcap)
 
 
-@jax.jit
 def ssd_scan(x, dtA, dt, B_, C_, init_state=None):
     if _STATE["pallas"]:
-        from repro.kernels import ssd_scan as sk
-
         return sk.ssd_scan_pallas(
             x, dtA, dt, B_, C_, init_state, interpret=_STATE["interpret"]
         )
-    return ref.ssd_reference(x, dtA, dt, B_, C_, init_state)
+    return _ref_ssd(x, dtA, dt, B_, C_, init_state)
 
 
-@functools.partial(jax.jit, static_argnames=("tile",))
-def quantize_int8(x, tile: int = 128):
-    if _STATE["pallas"]:
-        from repro.kernels import int8_transfer as ik
+def quantize_int8(x, tile: int = WIRE_TILE):
+    """Per-tile int8 quantization of the split boundary: the compiled
+    Pallas kernel on TPU (feature width a multiple of 128), the XLA
+    reference elsewhere."""
+    if on_tpu():
+        return ik.quantize_int8_pallas(x, tile=tile)
+    return _ref_quantize(x, tile=tile)
 
-        return ik.quantize_int8_pallas(x, tile=tile, interpret=_STATE["interpret"])
-    return ref.quantize_int8(x, tile=tile)
 
-
-@functools.partial(jax.jit, static_argnames=("dtype",))
 def dequantize_int8(q, scales, dtype=jnp.bfloat16):
-    if _STATE["pallas"]:
-        from repro.kernels import int8_transfer as ik
-
-        return ik.dequantize_int8_pallas(q, scales, dtype=dtype,
-                                         interpret=_STATE["interpret"])
-    return ref.dequantize_int8(q, scales, dtype=dtype)
+    if on_tpu():
+        return ik.dequantize_int8_pallas(q, scales, dtype=dtype)
+    return _ref_dequantize(q, scales, dtype=dtype)

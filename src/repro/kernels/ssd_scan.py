@@ -8,14 +8,20 @@ Within a chunk everything is the SSD matrix form: decay matrix L from a
 log-space cumulative sum, C B^T Hadamard L for the diagonal term, carried
 state for the off-diagonal term, state update via decay-to-end weights.
 
-Head-blocked so that VMEM holds (Q x Q) decay tiles per head-block plus
-the (hb, N, P) state: hb = 8 heads of P=64 at N=128 -> ~0.6 MiB state,
-(256 x 256) tiles -> 0.25 MiB each. MXU dims: Q and P multiples of 128/64.
+Layout: the wrapper puts heads ahead of sequence — x as (B, H, S, P),
+the per-step log decays and dt scales as (B, H, S) — so every block's
+last two dims are (chunk, P), (hb, chunk) or (chunk, N): multiples of
+the (8, 128) tile or full array dims for hb = 8. Mosaic has no cumsum,
+so the wrapper also takes the chunk-local cumulative sum of the log
+decays (an O(B*S*H) XLA op); the kernel reads it directly.
+
+Head-blocked so that VMEM holds (Q x Q) decay tiles per head plus the
+(hb, N, P) state: hb = 8 heads of P=64 at N=128 -> 0.25 MiB state,
+(256 x 256) tiles -> 0.25 MiB each.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dtA_ref, dts_ref, b_ref, c_ref, y_ref, state_out_ref,
+def _ssd_kernel(x_ref, cum_ref, dts_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, n_chunks: int, hb: int):
     ci = pl.program_id(2)
 
@@ -31,48 +37,42 @@ def _ssd_kernel(x_ref, dtA_ref, dts_ref, b_ref, c_ref, y_ref, state_out_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0].astype(jnp.float32)        # (Q, hb, P)
-    dtA = dtA_ref[0].astype(jnp.float32)    # (Q, hb)
-    dts = dts_ref[0].astype(jnp.float32)    # (Q, hb)
+    cum = cum_ref[0].astype(jnp.float32)    # (hb, Q) chunk-local cumsum
+    dts = dts_ref[0].astype(jnp.float32)    # (hb, Q)
     B_ = b_ref[0].astype(jnp.float32)       # (Q, N)
     C_ = c_ref[0].astype(jnp.float32)       # (Q, N)
 
-    q = x.shape[0]
-    cum = jnp.cumsum(dtA, axis=0)                            # (Q, hb)
+    q = B_.shape[0]
     cb = jax.lax.dot_general(
         C_, B_, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                        # (Q, Q)
-    xs = x * dts[:, :, None]                                 # (Q, hb, P)
-
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     tri = ii >= jj
 
-    state = state_ref[...]                                   # (hb, N, P)
-    y_acc = jnp.zeros_like(x)
     for h in range(hb):  # static unroll over the head block
-        Lh = jnp.where(tri, jnp.exp(cum[:, h][:, None] - cum[:, h][None, :]), 0.0)
-        scores = cb * Lh                                     # (Q, Q)
+        row = cum[h:h + 1, :]                                # (1, Q)
+        col = row.reshape(q, 1)                              # (Q, 1)
+        last = col[q - 1:q, :]                               # (1, 1)
+        xs = x_ref[0, h].astype(jnp.float32) * dts[h:h + 1, :].reshape(q, 1)
+        Lh = jnp.where(tri, jnp.exp(col - row), 0.0)         # (Q, Q)
         y_diag = jax.lax.dot_general(
-            scores, xs[:, h, :], (((1,), (0,)), ((), ())),
+            cb * Lh, xs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                    # (Q, P)
-        decay_in = jnp.exp(cum[:, h])                        # (Q,)
+        state_h = state_ref[h]                               # (N, P)
         y_off = jax.lax.dot_general(
-            C_ * decay_in[:, None], state[h], (((1,), (0,)), ((), ())),
+            C_ * jnp.exp(col), state_h, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                    # (Q, P)
-        y_acc = y_acc.at[:, h, :].set(y_diag + y_off)
+        y_ref[0, h] = (y_diag + y_off).astype(y_ref.dtype)
 
-        decay_end = jnp.exp(cum[-1, h] - cum[:, h])          # (Q,)
+        b_end = (B_ * jnp.exp(last - col)).T                 # (N, Q)
         s_chunk = jax.lax.dot_general(
-            B_ * decay_end[:, None], xs[:, h, :], (((0,), (0,)), ((), ())),
+            b_end, xs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                    # (N, P)
-        state = state.at[h].set(state[h] * jnp.exp(cum[-1, h]) + s_chunk)
-
-    state_ref[...] = state
-    y_ref[0] = y_acc.astype(y_ref.dtype)
+        state_ref[h] = state_h * jnp.exp(last) + s_chunk
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -89,8 +89,8 @@ def ssd_scan_pallas(
     init_state=None,     # must be None (kernel owns state init)
     *,
     chunk: int = 256,
-    head_block: int = 4,
-    interpret: bool = True,
+    head_block: int = 8,
+    interpret: bool = False,
 ):
     assert init_state is None, "pallas ssd owns the state"
     b, s, h, p = x.shape
@@ -102,26 +102,32 @@ def ssd_scan_pallas(
     n_chunks = s // chunk
     grid = (b, h // hb, n_chunks)
 
+    # Heads ahead of sequence; log decays summed within each chunk.
+    xt = x.transpose(0, 2, 1, 3)                                  # (B,H,S,P)
+    cum = jnp.cumsum(dtA.astype(jnp.float32).reshape(b, n_chunks, chunk, h),
+                     axis=2).reshape(b, s, h).transpose(0, 2, 1)  # (B,H,S)
+    dts = dt.transpose(0, 2, 1)                                   # (B,H,S)
+
     kernel = functools.partial(_ssd_kernel, n_chunks=n_chunks, hb=hb)
     y, state = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, hb, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, hb), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1, chunk, hb), lambda bi, hi, ci: (bi, ci, hi)),
+            pl.BlockSpec((1, hb, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, hb, chunk), lambda bi, hi, ci: (bi, hi, ci)),
+            pl.BlockSpec((1, hb, chunk), lambda bi, hi, ci: (bi, hi, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, hb, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, hb, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, hb, n, p), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hb, n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dtA, dt, B_, C_)
-    return y, state
+    )(xt, cum, dts, B_, C_)
+    return y.transpose(0, 2, 1, 3), state

@@ -42,7 +42,6 @@ from repro.config import (
     TrainConfig,
     cell_is_runnable,
 )
-from repro.compat import cost_analysis_dict
 from repro.configs import ARCH_IDS, get_config
 from repro.core.profiler import profile_lm
 from repro.core.splitter import choose_split
@@ -275,7 +274,7 @@ def lower_cell(
     compiled = lowered.compile()
     t1 = time.time()
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     hc = analyze_hlo(compiled.as_text())   # trip-count-aware, per device
     colls = hc.coll_by_kind

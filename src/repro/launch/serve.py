@@ -4,9 +4,10 @@ up a COS fleet.
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --tokens 16
     PYTHONPATH=src python -m repro.launch.serve --cos-fleet 4 --tenants 3
 
-On CPU this runs the reduced config (--smoke default); on real hardware
-the same driver jits the full config over the production mesh with the
-flash-decode cache sharding of distributed/sharding.cache_pspecs.
+By default this decodes the reduced (smoke) config; ``--full`` decodes
+the published config on one device. No mesh is built here: the sharded
+cache layout of distributed/sharding.cache_pspecs is exercised only by
+the dry-run (launch/dryrun.py).
 
 ``--cos-fleet N`` instead stands up an N-replica HAPI deployment through
 the :class:`repro.api.HapiCluster` facade (autoscaling up to
@@ -67,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 
 
@@ -391,6 +393,7 @@ def main(argv=None):
                          "ui.perfetto.dev); works with --cos-fleet and "
                          "--replay")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.replay:
         trace, v = replay_cos_trace(args.replay, routing=args.routing,
                                     placement=args.placement,

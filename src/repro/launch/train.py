@@ -1,20 +1,21 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b --smoke \
-        --steps 50 --batch 8 --seq 64 --ckpt /tmp/ckpt
+        --steps 50 --batch 8 --seq 64 --ckpt ckpt/
 
 Wires every substrate layer together: COS object store -> resumable data
 pipeline -> Hapi tier plan (Alg. 1 split + Eq. 4 COS batch) -> jit'd
 Hapi train step -> AdamW -> atomic sharded checkpoints. ``--kill-at``
-demonstrates fault tolerance (crash + exact-state resume). On real
-hardware the same driver runs the full configs over the production mesh
-(--mesh single|multi); on CPU use --smoke.
+demonstrates fault tolerance (crash + exact-state resume). The driver
+runs on one device: the smoke configs on CPU (default), the published
+configs with ``--full`` on an accelerator that holds them whole
+(``chip_smoke.py`` runs mamba2-1.3b this way on one TPU v5e).
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -22,11 +23,40 @@ import numpy as np
 from repro.checkpoint.ckpt import restore_checkpoint, save_checkpoint
 from repro.config import HapiConfig, RunConfig, ShapeConfig, TrainConfig
 from repro.configs import get_config, get_smoke_config
-from repro.core.tier_split import plan_tiers
+from repro.core.tier_split import TierPlan, plan_tiers
 from repro.cos.objectstore import ObjectStore
 from repro.data.pipeline import COSDataPipeline, PipelineState, synthetic_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
+from repro.models.transformer import Model
 from repro.train.steps import build_hapi_train_step, init_train_state
+
+
+class TrainSetup(NamedTuple):
+    model: Model
+    rc: RunConfig
+    plan: TierPlan
+    store: ObjectStore       # the seeded dataset, as COS objects
+
+
+def setup_training(arch: str, *, steps: int = 50, batch: int = 8,
+                   seq: int = 64, smoke: bool = True, compress: bool = False,
+                   lr: float = 3e-4, object_size: int = 0,
+                   dataset_batches: int = 4) -> TrainSetup:
+    """Config, tier plan and seeded dataset of one training run."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    shape = ShapeConfig("custom", "train", seq, batch)
+    hapi = HapiConfig(compress_transfer=compress, cos_batch_min=1)
+    tc = TrainConfig(learning_rate=lr, total_steps=steps, warmup_steps=max(2, steps // 10))
+    rc = RunConfig(model=cfg, shape=shape, hapi=hapi, train=tc)
+    plan = plan_tiers(cfg, shape, hapi, local_batch=batch)
+
+    # Dataset lives in the (simulated) COS as fixed-size objects.
+    store = ObjectStore()
+    data = synthetic_dataset(cfg, shape, n_samples=batch * dataset_batches,
+                             seed=tc.seed)
+    store.put_dataset("train", data, object_size=object_size or batch)
+    return TrainSetup(build_model(cfg), rc, plan, store)
 
 
 def run_training(
@@ -45,22 +75,17 @@ def run_training(
     object_size: int = 0,
     dataset_batches: int = 4,
 ):
-    cfg = get_smoke_config(arch) if smoke else get_config(arch)
-    shape = ShapeConfig("custom", "train", seq, batch)
-    hapi = HapiConfig(compress_transfer=compress, cos_batch_min=1)
-    tc = TrainConfig(learning_rate=lr, total_steps=steps, warmup_steps=max(2, steps // 10))
-    rc = RunConfig(model=cfg, shape=shape, hapi=hapi, train=tc)
-
-    model = build_model(cfg)
-    plan = plan_tiers(cfg, shape, hapi, local_batch=batch)
+    """Run the Hapi fine-tune loop. Returns the losses plus the compiled
+    step (``compiled``), its AOT compile time (``compile_seconds``) and the
+    wall time of each step up to its results being ready
+    (``step_seconds``)."""
+    model, rc, plan, store = setup_training(
+        arch, steps=steps, batch=batch, seq=seq, smoke=smoke,
+        compress=compress, lr=lr, object_size=object_size,
+        dataset_batches=dataset_batches)
+    cfg, tc = rc.model, rc.train
     print(f"[plan] split={plan.split}/{cfg.n_blocks} cos_batch={plan.cos_batch} "
           f"compress={plan.compress} ({plan.decision.reason})")
-
-    # Dataset lives in the (simulated) COS as fixed-size objects.
-    store = ObjectStore()
-    data = synthetic_dataset(cfg, shape, n_samples=batch * dataset_batches,
-                             seed=tc.seed)
-    store.put_dataset("train", data, object_size=object_size or batch)
     pstate = PipelineState()
 
     state = init_train_state(model, rc, plan, jax.random.PRNGKey(tc.seed))
@@ -73,11 +98,13 @@ def run_training(
             print(f"[resume] restored step {at}, object cursor {pstate.next_object}")
 
     step_fn = jax.jit(build_hapi_train_step(model, rc, plan), donate_argnums=(0,))
+    compiled = None
+    compile_seconds = 0.0
 
     pipe = COSDataPipeline(store, "train", global_batch=batch, state=pstate)
     it = iter(pipe)
     t0 = time.time()
-    losses = []
+    losses, step_seconds = [], []
     i = start_step
     while i < steps:
         try:
@@ -85,8 +112,13 @@ def run_training(
         except StopIteration:
             it = iter(pipe)
             continue
-        batch_np = {k: v for k, v in raw.items()}
-        state, metrics = step_fn(state, batch_np)
+        if compiled is None:
+            t = time.perf_counter()
+            compiled = step_fn.lower(state, raw).compile()
+            compile_seconds = time.perf_counter() - t
+        t = time.perf_counter()
+        state, metrics = jax.block_until_ready(compiled(state, raw))
+        step_seconds.append(time.perf_counter() - t)
         losses.append(float(metrics["loss"]))
         i += 1
         if i % log_every == 0 or i == steps:
@@ -101,7 +133,9 @@ def run_training(
             print(f"[kill] simulating crash at step {i}")
             return {"killed_at": i, "losses": losses}
 
-    return {"final_loss": losses[-1], "losses": losses, "steps": i}
+    return {"final_loss": losses[-1], "losses": losses, "steps": i,
+            "compiled": compiled, "compile_seconds": compile_seconds,
+            "step_seconds": step_seconds}
 
 
 def main(argv=None):
@@ -118,13 +152,15 @@ def main(argv=None):
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = run_training(
         args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         smoke=args.smoke, ckpt_dir=args.ckpt, ckpt_every=args.ckpt_every,
         kill_at=args.kill_at, compress=args.compress, lr=args.lr,
     )
     print({k: (round(v, 4) if isinstance(v, float) else v)
-           for k, v in out.items() if k != "losses"})
+           for k, v in out.items()
+           if k not in ("losses", "compiled", "step_seconds")})
 
 
 if __name__ == "__main__":

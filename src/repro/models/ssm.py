@@ -116,9 +116,13 @@ def ssd_chunked(x, dtA, dtx_scale, B, C, init_state=None, chunk: int = 256):
     def chunk_step(state, inp):
         xk, ak, dk, bk, ck = inp                           # (B,Q,...)
         cum = jnp.cumsum(ak, axis=1)                       # (B,Q,H)
-        # Within-chunk decay L[i,j] = exp(cum_i - cum_j), i >= j.
-        Lmat = jnp.exp(cum[:, :, None, :] - cum[:, None, :, :])   # (B,Q,Q,H)
-        Lmat = jnp.where(tri[None, :, :, None], Lmat, 0.0)
+        # Within-chunk decay L[i,j] = exp(cum_i - cum_j), i >= j. Mask the
+        # exponent, not its result: above the diagonal cum_i - cum_j > 0
+        # overflows to inf for strong decays, and exp's gradient there
+        # (0 * inf) would be NaN.
+        seg = jnp.where(tri[None, :, :, None],
+                        cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+        Lmat = jnp.exp(seg)                                # (B,Q,Q,H)
         cb = jnp.einsum("bqn,bkn->bqk", ck, bk, preferred_element_type=jnp.float32)
         scores = cb[..., None] * Lmat                      # (B,Q,Q,H)
         xs = xk.astype(jnp.float32) * dk[..., None]        # dt-scaled inputs

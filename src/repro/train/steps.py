@@ -31,9 +31,14 @@ class TrainState(NamedTuple):
     opt: OptState
 
 
+def init_split_params(model: Model, split: int, key):
+    """Seeded ``(frozen, trainable)`` params, built in one jitted program
+    (eager init of a billion-parameter stack dispatches op by op)."""
+    return jax.jit(lambda k: model.split_params(model.init(k), split))(key)
+
+
 def init_train_state(model: Model, rc: RunConfig, plan: TierPlan, key) -> TrainState:
-    params = model.init(key)
-    frozen, trainable = model.split_params(params, plan.split)
+    frozen, trainable = init_split_params(model, plan.split, key)
     return TrainState(frozen, trainable, init_opt_state(trainable, rc.train))
 
 

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
 from repro.distributed.collectives import (
     compressed_psum,
     decompress_boundary,
@@ -29,7 +28,7 @@ def test_compressed_psum_single_device():
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 128))
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(),
         out_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
         check_vma=False,

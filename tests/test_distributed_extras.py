@@ -1,4 +1,5 @@
 """Elastic re-meshing + pipeline parallelism + tier steps."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -11,6 +12,8 @@ import pytest
 from repro.config import HW, MeshSpec, RunConfig, ShapeConfig, TrainConfig
 from repro.distributed.elastic import plan_elastic_mesh, reshard_state
 from repro.distributed.pipeline import pipeline_bubble_fraction, pipeline_stages
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -73,7 +76,6 @@ PIPE_PROG = textwrap.dedent("""
     import sys
     sys.path.insert(0, "src")
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.distributed.pipeline import pipeline_stages
 
     S, M, D = 4, 8, 16
@@ -84,7 +86,7 @@ PIPE_PROG = textwrap.dedent("""
 
     fn = lambda sp, v: jnp.tanh(v @ sp["w"])
     body = pipeline_stages(fn, S, M, axis="stage")
-    piped = jax.jit(shard_map(
+    piped = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=({"w": P("stage")}, P("stage")),
         out_specs=P(), check_vma=False,
     ))({"w": w}, x)
@@ -99,7 +101,7 @@ PIPE_PROG = textwrap.dedent("""
 
 
 def test_pipeline_four_stage_subprocess():
-    r = subprocess.run([sys.executable, "-c", PIPE_PROG], cwd="/root/repo",
+    r = subprocess.run([sys.executable, "-c", PIPE_PROG], cwd=REPO_ROOT,
                        capture_output=True, text=True, timeout=300)
     assert "PIPE-OK" in r.stdout, r.stdout + r.stderr
 

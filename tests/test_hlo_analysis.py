@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis_dict
 from repro.launch.hlo_analysis import analyze_hlo, parse_module
 
 
@@ -34,7 +33,7 @@ def test_scan_unroll_parity():
     assert abs(fs - expected) / expected < 0.05
     assert abs(fu - expected) / expected < 0.05
     # XLA's own count misses the trip count
-    assert cost_analysis_dict(cs)["flops"] < 0.2 * expected
+    assert cs.cost_analysis()["flops"] < 0.2 * expected
 
 
 def test_nested_scan_multiplies():

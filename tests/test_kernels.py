@@ -197,13 +197,24 @@ def test_int8_wire_savings():
 
 
 def test_ops_dispatch_pallas_toggle():
+    """The toggle is read on every call: switched on, ops.flash_attention
+    traces the Pallas kernel; switched off, the XLA reference. Both agree
+    with the interpreted kernel and with ref.flash_attention."""
     from repro.kernels import ops
 
     x = jax.random.normal(KEY, (2, 64, 4, 32))
+    trace = lambda: str(jax.make_jaxpr(
+        lambda a: ops.flash_attention(a, a, a, causal=True))(x))
     try:
         ops.use_pallas(True, interpret=True)
-        o1 = ops.flash_attention(x, x, x, causal=True)
+        assert "pallas_call" in trace()
+        o_on = ops.flash_attention(x, x, x, causal=True)
     finally:
         ops.use_pallas(False)
-    o2 = ops.flash_attention(x, x, x, causal=True)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5, rtol=2e-5)
+    assert "pallas_call" not in trace()
+    o_off = ops.flash_attention(x, x, x, causal=True)
+    kernel = flash_attention_pallas(x, x, x, causal=True, interpret=True)
+    exp = ref.flash_attention(x, x, x, causal=True)
+    for out in (o_on, o_off, kernel):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   atol=2e-5, rtol=2e-5)

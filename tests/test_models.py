@@ -167,3 +167,23 @@ def test_vision_models_split_consistency():
         mid = len(vm.layer_names) // 2
         y2 = vm.apply_range(params, vm.apply_range(params, x, 0, mid), mid, None)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y2), atol=1e-4)
+
+
+def test_ssd_gradient_finite_under_strong_decay():
+    """Summed log decays past exp's f32 range within one chunk (here 127)
+    must not turn the SSD gradient to NaN through the masked upper
+    triangle of the decay matrix."""
+    from repro.models.ssm import ssd_chunked
+
+    b, s, h, p, n = 1, 128, 2, 8, 4
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    B_ = jax.random.normal(ks[1], (b, s, n))
+    C_ = jax.random.normal(ks[2], (b, s, n))
+    dt = jnp.ones((b, s, h))
+
+    def f(dtA):
+        return ssd_chunked(x, dtA, dt, B_, C_, None, chunk=128)[0].sum()
+
+    g = jax.grad(f)(-jnp.ones((b, s, h)))
+    assert bool(jnp.isfinite(g).all())

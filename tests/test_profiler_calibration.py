@@ -1,5 +1,6 @@
 """Paper §5.3 hybrid calibration + multihost data loading + dry-run
 integration (subprocess: one real lower+compile on 256 fake devices)."""
+import pathlib
 import subprocess
 import sys
 
@@ -8,6 +9,8 @@ import pytest
 
 from repro.core.profiler import calibrate_profile, extrapolation_error, profile_layered
 from repro.models.vision import alexnet
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_calibration_only_increases():
@@ -71,7 +74,7 @@ def test_dryrun_one_cell_subprocess():
     import os
 
     env = dict(os.environ, PYTHONPATH="src", REPRO_DRYRUN_DEVICES="256")
-    r = subprocess.run(DRYRUN_CMD, cwd="/root/repo", env=env,
+    r = subprocess.run(DRYRUN_CMD, cwd=REPO_ROOT, env=env,
                        capture_output=True, text=True, timeout=420)
     assert "[ok] whisper-small" in r.stdout, r.stdout + r.stderr
     assert "dom=" in r.stdout

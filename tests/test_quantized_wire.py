@@ -21,6 +21,7 @@ from repro.core.splitter import choose_split
 from repro.cos.objectstore import synthetic_image_store
 from repro.cos.server import HapiServer, PostRequest
 from repro.kernels import ops, ref
+from repro.kernels.int8_transfer import dequantize_int8_pallas
 from repro.kernels.ops import INT8_WIRE_RATIO, WIRE_TILE, compression_ratio
 from repro.models.vision import alexnet
 
@@ -146,22 +147,56 @@ def test_live_raw_executor_charged_with_ratio(prof):
 
 
 # ---------------------------------------------------------------------------
-# Dequantize dtype dispatch: identical on both backends
+# Int8 dispatch: the platform picks the kernel; both paths agree
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ops_dequantize_dtype_dispatch(dtype):
+    """The Pallas kernel (interpreted) and the reference dequantize to the
+    requested dtype identically, and ops (off-TPU) runs the reference."""
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 256), jnp.float32) * 2
     q, s = ref.quantize_int8(x)
-    try:
-        ops.use_pallas(True, interpret=True)
-        a = ops.dequantize_int8(q, s, dtype=dtype)
-    finally:
-        ops.use_pallas(False)
-    b = ops.dequantize_int8(q, s, dtype=dtype)
-    assert a.dtype == jnp.dtype(dtype)
-    assert b.dtype == jnp.dtype(dtype)
+    a = dequantize_int8_pallas(q, s, dtype=dtype, interpret=True)
+    b = ref.dequantize_int8(q, s, dtype=dtype)
+    c = ops.dequantize_int8(q, s, dtype=dtype)
+    for out in (a, b, c):
+        assert out.dtype == jnp.dtype(dtype)
     np.testing.assert_array_equal(np.asarray(a, np.float32),
                                   np.asarray(b, np.float32))
+    np.testing.assert_array_equal(np.asarray(c, np.float32),
+                                  np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_ops_int8_dispatch_follows_platform(tpu, monkeypatch):
+    """On TPU the int8 pair traces the Pallas kernels (compiled, never
+    interpreted); elsewhere the XLA reference."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    x = jnp.ones((4, 256), jnp.float32)
+    # Fresh lambdas: make_jaxpr caches traces by function identity.
+    qs = jax.make_jaxpr(lambda a: ops.quantize_int8(a))(x)
+    dq = jax.make_jaxpr(lambda q, s: ops.dequantize_int8(q, s))(
+        *ref.quantize_int8(x))
+    for jaxpr in (qs, dq):
+        assert ("pallas_call" in str(jaxpr)) == tpu
+        assert "interpret=True" not in str(jaxpr)
+
+
+def test_ops_int8_on_tpu_rejects_unaligned_width(monkeypatch):
+    """A width the compiled kernel cannot lay out is a clear error on
+    TPU, not a Mosaic failure and not a silent fallback to the reference."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.quantize_int8(jnp.ones((4, 96), jnp.float32))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.dequantize_int8(jnp.ones((4, 96), jnp.int8),
+                            jnp.ones((4, 3), jnp.float32))
+
+
+def test_use_pallas_refuses_interpret_on_tpu(monkeypatch):
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="off-TPU"):
+        ops.use_pallas(True, interpret=True)
+    assert not ops.pallas_enabled()
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,110 @@
+"""The Pallas kernels and the int8 train step compile for a described TPU
+v5e (no chip needed): what interpret-mode tests cannot show — tiling,
+layout and VMEM limits — is checked by the TPU compiler itself.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and every
+test worker imports this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.int8_transfer import dequantize_int8_pallas, quantize_int8_pallas
+from repro.kernels.ssd_scan import ssd_scan_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler here: nothing to check
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # A compile for a described chip cannot be read back from the
+        # persistent cache without one: keep the cache out of it.
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _hlo(f, *args) -> str:
+    return jax.jit(f).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("rows,d", [(16384, 2048), (20, 2048), (4096, 768)])
+def test_int8_pair_compiles(one_chip, rows, d):
+    q = _hlo(quantize_int8_pallas, _spec(one_chip, (rows, d)))
+    dq = _hlo(lambda a, s: dequantize_int8_pallas(a, s),
+              _spec(one_chip, (rows, d), jnp.int8),
+              _spec(one_chip, (rows, d // 128), jnp.float32))
+    assert "tpu_custom_call" in q and "tpu_custom_call" in dq
+
+
+def test_flash_attention_compiles(one_chip):
+    x = _spec(one_chip, (1, 4096, 8, 128))
+    assert "tpu_custom_call" in _hlo(
+        lambda q, k, v: flash_attention_pallas(q, k, v), x, x, x)
+
+
+def test_decode_attention_compiles_mistral_nemo(one_chip):
+    """Hq 32, Hkv 8, hd 128 over an 8192-token cache."""
+    cache = _spec(one_chip, (1, 8192, 8, 128))
+    assert "tpu_custom_call" in _hlo(
+        lambda q, k, v, n: decode_attention_pallas(q, k, v, n),
+        _spec(one_chip, (1, 32, 128)), cache, cache,
+        _spec(one_chip, (), jnp.int32))
+
+
+def test_ssd_scan_compiles_mamba2(one_chip):
+    """H 64, P 64, N 128, chunk 256 over a 2048-token sequence."""
+    f32 = lambda shape: _spec(one_chip, shape, jnp.float32)
+    assert "tpu_custom_call" in _hlo(
+        lambda x, a, d, b, c: ssd_scan_pallas(x, a, d, b, c),
+        _spec(one_chip, (1, 2048, 64, 64)), f32((1, 2048, 64)),
+        f32((1, 2048, 64)), _spec(one_chip, (1, 2048, 128)),
+        _spec(one_chip, (1, 2048, 128)))
+
+
+def test_int8_train_step_holds_the_kernels(one_chip, monkeypatch):
+    """The split step with the int8 boundary, traced as on a TPU, compiles
+    with the Pallas kernels in it (a mamba2 config cut to 128 wide)."""
+    from repro.config import HapiConfig, RunConfig, ShapeConfig, TrainConfig
+    from repro.configs import get_smoke_config
+    from repro.core.tier_split import plan_tiers
+    from repro.kernels import ops
+    from repro.models.api import build_model
+    from repro.train.steps import build_hapi_train_step, init_train_state
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_smoke_config("mamba2-1.3b"), d_model=128,
+                              param_dtype="bfloat16", compute_dtype="bfloat16")
+    shape = ShapeConfig("t", "train", 64, 4)
+    hapi = HapiConfig(compress_transfer=True, cos_batch_min=1)
+    rc = RunConfig(model=cfg, shape=shape, hapi=hapi, train=TrainConfig())
+    model = build_model(cfg)
+    plan = plan_tiers(cfg, shape, hapi, local_batch=4)
+    place = lambda tree: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+    state = place(jax.eval_shape(
+        lambda k: init_train_state(model, rc, plan, k), jax.random.PRNGKey(0)))
+    tokens = _spec(one_chip, (4, 64), jnp.int32)
+    hlo = _hlo(build_hapi_train_step(model, rc, plan), state,
+               {"tokens": tokens, "labels": tokens})
+    assert hlo.count("tpu_custom_call") >= 2
