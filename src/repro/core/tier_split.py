@@ -27,6 +27,7 @@ from repro.core.profiler import LayerProfile, profile_lm
 from repro.core.splitter import SplitDecision, choose_split
 from repro.kernels import ops
 from repro.models.transformer import Model
+from repro.obs import device_scope
 
 
 @dataclass(frozen=True)
@@ -105,9 +106,11 @@ def make_extract_fn(model: Model, plan: TierPlan) -> Callable:
                 return None, ops.quantize_int8(acts)
             return None, acts
 
-        _, out = jax.lax.scan(body, None, mbatches)
-        # Re-flatten microbatch axis: (nb, mb, ...) -> (B, ...)
-        return jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), out)
+        # The scan's own slicing and stacking count with the extract.
+        with device_scope("hapi.extract"):
+            _, out = jax.lax.scan(body, None, mbatches)
+            # Re-flatten microbatch axis: (nb, mb, ...) -> (B, ...)
+            return jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), out)
 
     return extract
 
@@ -148,7 +151,8 @@ def make_tune_loss_fn(model: Model, plan: TierPlan) -> Callable:
             # hardcoded bf16 output.
             acts = ops.dequantize_int8(
                 q, scales, dtype=dtype_of(model.cfg.compute_dtype))
-        return model.loss_suffix(trainable, acts, batch, plan.split)
+        with device_scope("hapi.tune"):
+            return model.loss_suffix(trainable, acts, batch, plan.split)
 
     return tune_loss
 

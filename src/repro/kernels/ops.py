@@ -18,6 +18,7 @@ from repro.kernels import flash_attention as fk
 from repro.kernels import int8_transfer as ik
 from repro.kernels import ref
 from repro.kernels import ssd_scan as sk
+from repro.obs import device_scope
 
 _STATE = {"pallas": False, "interpret": False}
 
@@ -113,13 +114,15 @@ def ssd_scan(x, dtA, dt, B_, C_, init_state=None):
 def quantize_int8(x, tile: int = WIRE_TILE):
     """Per-tile int8 quantization of the split boundary: the compiled
     Pallas kernel on TPU (feature width a multiple of 128), the XLA
-    reference elsewhere."""
-    if on_tpu():
-        return ik.quantize_int8_pallas(x, tile=tile)
-    return _ref_quantize(x, tile=tile)
+    reference elsewhere. Both carry the ``hapi.quantize`` scope."""
+    with device_scope("hapi.quantize"):
+        if on_tpu():
+            return ik.quantize_int8_pallas(x, tile=tile)
+        return _ref_quantize(x, tile=tile)
 
 
 def dequantize_int8(q, scales, dtype=jnp.bfloat16):
-    if on_tpu():
-        return ik.dequantize_int8_pallas(q, scales, dtype=dtype)
-    return _ref_dequantize(q, scales, dtype=dtype)
+    with device_scope("hapi.dequantize"):
+        if on_tpu():
+            return ik.dequantize_int8_pallas(q, scales, dtype=dtype)
+        return _ref_dequantize(q, scales, dtype=dtype)

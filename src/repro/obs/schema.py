@@ -14,6 +14,11 @@ metrics registry may emit, mirroring how
   both-direction guarantee.
 * :data:`TIERS` — the process-level grouping of the Perfetto export:
   one ``pid`` per tier, one ``tid`` per resource track within it.
+* :data:`DEVICE_SCOPES` — the phases of the split fine-tune program,
+  named on the device ops themselves (``device_scope`` sites). Unlike
+  the three sets above these are not virtual time: each is a
+  ``jax.named_scope``, compile-time metadata (``op_name``) that a
+  profiler trace of the compiled program carries on every op.
 
 The tracer and the registry validate against these sets at emission
 time, so an unregistered name fails the emitting run loudly instead of
@@ -57,6 +62,31 @@ METRIC_KEYS = frozenset({
     # scaling signals
     "accel_utilization",
 })
+
+
+#: Named scopes of the split fine-tune program's phases on the device.
+DEVICE_SCOPES = frozenset({
+    "hapi.extract",      # frozen prefix at the COS batch (storage side)
+    "hapi.quantize",     # int8 boundary, storage side
+    "hapi.dequantize",   # int8 boundary, compute side
+    "hapi.tune",         # suffix forward and backward, head, loss, accumulation
+    "hapi.adamw",        # gradient averaging, clip and the AdamW update
+})
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)`` for a registered phase of the program.
+
+    The scope costs nothing at run time: it only names the ops traced
+    inside it, in their ``op_name`` metadata (the backward pass keeps it
+    as ``transpose(jvp(<name>))``)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(
+            f"device scope {name!r} is not in repro.obs.schema.DEVICE_SCOPES; "
+            f"register it there so trace readers can find it")
+    import jax
+
+    return jax.named_scope(name)
 
 
 def validate_span_name(name: str) -> str:

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.config import TrainConfig
 from repro.models.module import dtype_of
+from repro.obs import device_scope
 
 
 class OptState(NamedTuple):
@@ -61,6 +62,11 @@ def global_norm(tree) -> jnp.ndarray:
 
 def adamw_update(params, grads, opt: OptState, tc: TrainConfig):
     """One AdamW step. Returns (new_params, new_opt, metrics)."""
+    with device_scope("hapi.adamw"):
+        return _adamw_update(params, grads, opt, tc)
+
+
+def _adamw_update(params, grads, opt: OptState, tc: TrainConfig):
     step = opt.step + 1
     lr = lr_schedule(step, tc)
     gnorm = global_norm(grads)

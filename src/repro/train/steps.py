@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.config import RunConfig
 from repro.core.tier_split import TierPlan, make_extract_fn, make_tune_loss_fn
 from repro.models.transformer import Model
+from repro.obs import device_scope
 from repro.optim.adamw import OptState, adamw_update, init_opt_state
 
 
@@ -70,7 +71,8 @@ def build_hapi_train_step(
                 g_acc, loss_acc = carry
                 acts, bchunk = get_acts(bt)
                 loss, g = jax.value_and_grad(tune)(state.trainable, acts, bchunk)
-                g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
+                with device_scope("hapi.tune"):
+                    g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
                 if constrain:
                     # Keep the accumulator ZeRO-sharded inside the scan carry.
                     g_acc = constrain(g_acc, "grads")
@@ -120,7 +122,8 @@ def build_hapi_train_step(
             (grads, loss_sum), _ = jax.lax.scan(
                 gstep_factory(get_acts), (zeros, 0.0), (acts_c, batch_c))
 
-        grads = jax.tree.map(lambda g: g / n_chunks, grads)
+        with device_scope("hapi.adamw"):
+            grads = jax.tree.map(lambda g: g / n_chunks, grads)
         new_trainable, new_opt, om = adamw_update(state.trainable, grads, state.opt, tc)
         metrics = {"loss": loss_sum / n_chunks, **om}
         return TrainState(state.frozen, new_trainable, new_opt), metrics
@@ -169,7 +172,8 @@ def build_tier_steps(model: Model, rc: RunConfig, plan: TierPlan,
             g_acc, loss_acc = carry
             a, bt = chunk
             loss, g = jax.value_and_grad(tune)(trainable, a, bt)
-            g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
+            with device_scope("hapi.tune"):
+                g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
             if constrain:
                 g_acc = constrain(g_acc, "grads")
             return (g_acc, loss_acc + loss), None
@@ -178,7 +182,8 @@ def build_tier_steps(model: Model, rc: RunConfig, plan: TierPlan,
         if constrain:
             zeros = constrain(zeros, "grads")
         (grads, loss_sum), _ = jax.lax.scan(gstep, (zeros, 0.0), (acts_c, batch_c))
-        grads = jax.tree.map(lambda g: g / n_chunks, grads)
+        with device_scope("hapi.adamw"):
+            grads = jax.tree.map(lambda g: g / n_chunks, grads)
         new_trainable, new_opt, om = adamw_update(trainable, grads, opt, tc)
         return new_trainable, new_opt, {"loss": loss_sum / n_chunks, **om}
 

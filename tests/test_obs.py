@@ -4,7 +4,8 @@
   ``src/repro`` (grep for the ``tr.emit(``/``tr.begin(`` convention) is
   in :data:`repro.obs.SPAN_NAMES` and vice versa; same for metric keys
   (``mx.inc``/``mx.observe``/``mx.gauge_set``) vs
-  :data:`repro.obs.METRIC_KEYS`;
+  :data:`repro.obs.METRIC_KEYS`, and ``device_scope("...")`` sites vs
+  :data:`repro.obs.DEVICE_SCOPES`;
 * determinism: same seed => identical span digest; tracing on vs off
   leaves the event-log digest byte-identical (the golden hashes in
   tests/test_scheduler.py run with tracing on, so this is the only
@@ -23,11 +24,14 @@
 import json
 import os
 import re
+import subprocess
+import sys
 
 import pytest
 
 from repro.api import HapiCluster, TenantSpec
 from repro.obs import (
+    DEVICE_SCOPES,
     METRIC_KEYS,
     SPAN_NAMES,
     MetricsRegistry,
@@ -46,6 +50,8 @@ SPAN_PAT = re.compile(
     r"\btr\.(?:emit_fast|emit|begin)\(\s*[\"']([a-z][a-z0-9_.-]{1,30})[\"']")
 METRIC_PAT = re.compile(
     r"\bmx\.(?:inc|observe|gauge_set)\(\s*[\"']([a-z][a-z0-9_.-]{1,40})[\"']")
+SCOPE_PAT = re.compile(
+    r"\bdevice_scope\(\s*[\"']([a-z][a-z0-9_.-]{1,40})[\"']")
 
 
 def _grep_src(pat):
@@ -100,6 +106,29 @@ def test_schema_has_no_phantom_metric_keys():
     phantom = METRIC_KEYS - _grep_src(METRIC_PAT)
     assert not phantom, (
         f"schema metric keys no longer emitted anywhere: {sorted(phantom)}")
+
+
+def test_every_device_scope_site_is_in_schema():
+    used = _grep_src(SCOPE_PAT)
+    assert used, "grep found no device_scope sites at all"
+    missing = used - DEVICE_SCOPES
+    assert not missing, (
+        f"device_scope names not registered in "
+        f"repro.obs.schema.DEVICE_SCOPES: {sorted(missing)}")
+
+
+def test_schema_has_no_phantom_device_scopes():
+    phantom = DEVICE_SCOPES - _grep_src(SCOPE_PAT)
+    assert not phantom, (
+        f"schema device scopes no longer used anywhere: {sorted(phantom)}")
+
+
+def test_obs_import_leaves_jax_unloaded():
+    """Simulator-only users of repro.obs do not pay for importing jax."""
+    code = "import sys, repro.obs; sys.exit('jax' in sys.modules)"
+    env = dict(os.environ, PYTHONPATH=os.path.dirname(SRC_ROOT))
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0
 
 
 def test_unknown_names_rejected():
