@@ -1,9 +1,10 @@
 """Public wrappers for the Pallas kernels, with backend dispatch.
 
-The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``) follows
-the platform: on TPU it runs the compiled Pallas kernels, elsewhere the
-pure-XLA references. Flash, decode and SSD attention sit off the model
-path; ``use_pallas(True)`` routes them to their Pallas kernels, and
+The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``) and the
+SSD scan follow the platform: on TPU they run the compiled Pallas
+kernels, elsewhere the pure-XLA references (the SSD scan also where its
+blocks cannot tile the input). Flash and decode attention sit off the
+model path; ``use_pallas(True)`` routes them to their Pallas kernels, and
 ``use_pallas(True, interpret=True)`` runs those kernels in the Pallas
 interpreter off-TPU (tests only). Every wrapper reads the switch when it
 is called, so a toggle takes effect on the next call.
@@ -63,7 +64,7 @@ def on_tpu() -> bool:
 
 
 def use_pallas(enable: bool = True, *, interpret: bool = False) -> None:
-    """Route flash, decode and SSD attention to their Pallas kernels.
+    """Route flash and decode attention to their Pallas kernels.
     ``interpret`` runs them in the Pallas interpreter; it is refused on
     TPU, where the kernels compile."""
     if interpret and on_tpu():
@@ -79,7 +80,6 @@ def pallas_enabled() -> bool:
 _ref_flash = jax.jit(ref.flash_attention,
                      static_argnames=("causal", "window", "softcap"))
 _ref_decode = jax.jit(ref.decode_attention, static_argnames=("softcap",))
-_ref_ssd = jax.jit(ref.ssd_reference)
 _ref_quantize = jax.jit(ref.quantize_int8, static_argnames=("tile",))
 _ref_dequantize = jax.jit(ref.dequantize_int8, static_argnames=("dtype",))
 
@@ -103,12 +103,18 @@ def decode_attention(q, k_cache, v_cache, length, *, softcap=None):
     return _ref_decode(q, k_cache, v_cache, length, softcap=softcap)
 
 
-def ssd_scan(x, dtA, dt, B_, C_, init_state=None):
-    if _STATE["pallas"]:
-        return sk.ssd_scan_pallas(
-            x, dtA, dt, B_, C_, init_state, interpret=_STATE["interpret"]
-        )
-    return _ref_ssd(x, dtA, dt, B_, C_, init_state)
+def ssd_scan(x, dtA, dt, B_, C_, D, init_state=None, *, chunk: int = 256):
+    """Chunked SSD scan with its D skip: y = SSD(x) + D x in x's dtype,
+    (B, S, H, P), and the final state (B, H, N, P) f32. The compiled
+    Pallas pair on TPU, ``ref.ssd_chunked`` elsewhere, and on TPU too
+    where an initial state is given or the blocks cannot tile the input
+    (``ssd_scan.plan_blocks``)."""
+    if (on_tpu() and init_state is None
+            and sk.plan_blocks(x.shape, B_.shape[-1], chunk) is not None):
+        return sk.ssd_scan_pallas(x, dtA, dt, B_, C_, D, chunk=chunk)
+    y, state = ref.ssd_chunked(x, dtA, dt, B_, C_, init_state, chunk)
+    y = y + D[None, None, :, None] * x.astype(jnp.float32)
+    return y.astype(x.dtype), state
 
 
 def quantize_int8(x, tile: int = WIRE_TILE):

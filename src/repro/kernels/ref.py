@@ -105,6 +105,66 @@ def ssd_reference(
     return jnp.moveaxis(ys, 0, 1), final
 
 
+def ssd_chunked(x, dtA, dtx_scale, B, C, init_state=None, chunk: int = 256):
+    """Chunked SSD scan in XLA: the twin of the Pallas pair in
+    ``ssd_scan.py``, and the path off TPU.
+
+    x:   (B, S, H, P)    head inputs
+    dtA: (B, S, H)       log-decay per step (= dt * A, A < 0)
+    dtx_scale: (B, S, H) dt multiplier applied to inputs
+    B,C: (B, S, N)       input/output projections (single group)
+    Returns (y (B,S,H,P), final_state (B,H,N,P)).
+    """
+    b, s, h, p = x.shape
+    n = B.shape[-1]
+    q = min(chunk, s)
+    assert s % q == 0
+    nc = s // q
+
+    # One chunk in flight at a time (scan over chunks): the working set is
+    # O(B*Q*Q*H) instead of O(B*S*Q*H).
+    xc = jnp.moveaxis(x.reshape(b, nc, q, h, p), 1, 0)
+    dtAc = jnp.moveaxis(dtA.reshape(b, nc, q, h).astype(jnp.float32), 1, 0)
+    dtsc = jnp.moveaxis(dtx_scale.reshape(b, nc, q, h).astype(jnp.float32), 1, 0)
+    Bc = jnp.moveaxis(B.reshape(b, nc, q, n), 1, 0)
+    Cc = jnp.moveaxis(C.reshape(b, nc, q, n), 1, 0)
+
+    if init_state is None:
+        init_state = jnp.zeros((b, h, n, p), jnp.float32)
+    tri = jnp.tril(jnp.ones((q, q), bool))
+
+    def chunk_step(state, inp):
+        xk, ak, dk, bk, ck = inp                           # (B,Q,...)
+        cum = jnp.cumsum(ak, axis=1)                       # (B,Q,H)
+        # Within-chunk decay L[i,j] = exp(cum_i - cum_j), i >= j. Mask the
+        # exponent, not its result: above the diagonal cum_i - cum_j > 0
+        # overflows to inf for strong decays, and exp's gradient there
+        # (0 * inf) would be NaN.
+        seg = jnp.where(tri[None, :, :, None],
+                        cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+        Lmat = jnp.exp(seg)                                # (B,Q,Q,H)
+        cb = jnp.einsum("bqn,bkn->bqk", ck, bk, preferred_element_type=jnp.float32)
+        scores = cb[..., None] * Lmat                      # (B,Q,Q,H)
+        xs = xk.astype(jnp.float32) * dk[..., None]        # dt-scaled inputs
+        y_diag = jnp.einsum("bqkh,bkhp->bqhp", scores, xs)
+        # Carried-state contribution.
+        decay_in = jnp.exp(cum)                            # (B,Q,H)
+        y_off = jnp.einsum(
+            "bqn,bhnp,bqh->bqhp", ck.astype(jnp.float32), state, decay_in
+        )
+        # State update.
+        decay_to_end = jnp.exp(cum[:, -1:, :] - cum)       # (B,Q,H)
+        s_chunk = jnp.einsum(
+            "bqn,bqh,bqhp->bhnp", bk.astype(jnp.float32), decay_to_end, xs
+        )
+        new_state = state * jnp.exp(cum[:, -1, :])[:, :, None, None] + s_chunk
+        return new_state, (y_diag + y_off)
+
+    final_state, ys = jax.lax.scan(chunk_step, init_state, (xc, dtAc, dtsc, Bc, Cc))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p)
+    return y, final_state
+
+
 # ---------------------------------------------------------------------------
 # Int8 boundary compression oracle
 # ---------------------------------------------------------------------------

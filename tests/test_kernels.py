@@ -80,44 +80,128 @@ def test_decode_attention(b, s, hq, hkv, hd, length, dtype):
     )
 
 
+def _ssd_inputs(b, s, h, p, n, strong=False, dtype=jnp.float32):
+    """SSD inputs as the mixer makes them, (x, dtA, dt, B, C, D); ``strong``
+    gives decays whose exponents above the diagonal overflow f32 unless
+    masked."""
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)) + (3.0 if strong else 0.0))
+    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3 + (1.5 if strong else 0.0))
+    B_ = (jax.random.normal(ks[3], (b, s, n)) * 0.3).astype(dtype)
+    C_ = (jax.random.normal(ks[4], (b, s, n)) * 0.3).astype(dtype)
+    D = jax.random.normal(ks[5], (h,))
+    return x, dt * A, dt, B_, C_, D
+
+
+def _with_skip(scan, args):
+    """An SSD scan without the skip, ``scan(x, dtA, dt, B, C)``, plus
+    D x, cast to x's dtype as the mixer casts it."""
+    x, D = args[0], args[5]
+    y, state = scan(*args[:5])
+    return (y + D[None, None, :, None] * x.astype(jnp.float32)).astype(x.dtype), state
+
+
 @pytest.mark.parametrize(
-    "b,s,h,p,n,chunk,hb",
+    "b,s,h,p,n,chunk",
     [
-        (2, 512, 8, 64, 128, 128, 4),
-        (1, 256, 4, 32, 64, 64, 4),
-        (1, 256, 4, 32, 16, 128, 2),   # jamba-like small state
-        (2, 128, 8, 64, 128, 128, 8),  # single chunk
+        (2, 512, 8, 64, 128, 128),
+        (1, 256, 4, 32, 64, 64),
+        (1, 256, 4, 32, 16, 128),   # jamba-like small state
+        (2, 128, 8, 64, 128, 128),  # single chunk
     ],
 )
-def test_ssd_scan(b, s, h, p, n, chunk, hb):
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
-    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
-    B_ = jax.random.normal(ks[3], (b, s, n)) * 0.3
-    C_ = jax.random.normal(ks[4], (b, s, n)) * 0.3
-    y, st = ssd_scan_pallas(x, dt * A, dt, B_, C_, chunk=chunk, head_block=hb,
-                            interpret=True)
-    ye, ste = ref.ssd_reference(x, dt * A, dt, B_, C_)
+def test_ssd_scan(b, s, h, p, n, chunk):
+    args = _ssd_inputs(b, s, h, p, n)
+    y, st = ssd_scan_pallas(*args, chunk=chunk, interpret=True)
+    ye, ste = _with_skip(ref.ssd_reference, args)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(st), np.asarray(ste), atol=2e-3, rtol=2e-3)
 
 
 def test_ssd_kernel_matches_chunked_model_path():
-    """The model's XLA SSD (ssm.ssd_chunked) and the Pallas kernel agree."""
-    from repro.models.ssm import ssd_chunked
-
-    ks = jax.random.split(KEY, 5)
-    b, s, h, p, n = 1, 256, 4, 32, 64
-    x = jax.random.normal(ks[0], (b, s, h, p))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
-    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
-    B_ = jax.random.normal(ks[3], (b, s, n)) * 0.3
-    C_ = jax.random.normal(ks[4], (b, s, n)) * 0.3
-    y1, s1 = ssd_scan_pallas(x, dt * A, dt, B_, C_, chunk=64, head_block=2, interpret=True)
-    y2, s2 = ssd_chunked(x, dt * A, dt, B_, C_, None, chunk=64)
+    """The model's XLA SSD (ref.ssd_chunked) and the Pallas kernel agree,
+    with a grid chunk of 256 swept in two sub-chunks."""
+    args = _ssd_inputs(1, 512, 4, 32, 64)
+    y1, s1 = ssd_scan_pallas(*args, chunk=256, interpret=True)
+    y2, s2 = _with_skip(lambda *a: ref.ssd_chunked(*a, None, chunk=256), args)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize(
+    "b,s,h,p,n,chunk,strong",
+    [
+        (2, 256, 4, 32, 16, 64, False),     # four chunks, one head block
+        (1, 256, 4, 64, 32, 128, True),     # strong decay (masked exponent)
+        (1, 512, 16, 64, 16, 256, False),   # two head blocks, two sub-chunks a chunk
+        (2, 512, 8, 128, 16, 256, True),    # P of 128 lanes: one head a group
+        (1, 128, 2, 32, 16, 128, False),    # one chunk, H x P under 128 lanes
+    ],
+)
+def test_ssd_scan_grad(b, s, h, p, n, chunk, strong):
+    """The custom VJP's backward kernel against autodiff of the XLA scan,
+    with cotangents on y and on the final state, for bf16 x, B and C as
+    the mixer feeds them; the forward also against the sequential oracle."""
+    args = _ssd_inputs(b, s, h, p, n, strong, jnp.bfloat16)
+    ks = jax.random.split(jax.random.PRNGKey(1), 2)
+    wy = jax.random.normal(ks[0], (b, s, h, p))
+    ws = jax.random.normal(ks[1], (b, h, n, p))
+
+    def loss(scan):
+        def f(*a):
+            y, st = scan(*a)
+            return jnp.sum(y * wy) + jnp.sum(st * ws)
+        return f
+
+    kernel = lambda *a: ssd_scan_pallas(*a, chunk=chunk, interpret=True)
+    xla = lambda *a: _with_skip(lambda *u: ref.ssd_chunked(*u, None, chunk), a)
+    ye, _ = _with_skip(ref.ssd_reference, args)
+    np.testing.assert_allclose(np.asarray(kernel(*args)[0], np.float32),
+                               np.asarray(ye, np.float32), atol=3e-2, rtol=1e-2)
+    got = jax.grad(loss(kernel), argnums=range(6))(*args)
+    exp = jax.grad(loss(xla), argnums=range(6))(*args)
+    for name, g, e in zip(("x", "dtA", "dt", "B", "C", "D"), got, exp):
+        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+        assert np.isfinite(g).all(), name
+        # x, B and C take bf16 cotangents: within a bf16 rounding of the largest
+        tol = 1e-2 if name in ("x", "B", "C") else 1e-4
+        np.testing.assert_allclose(g, e, atol=tol * np.abs(e).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("s,chunk,p,init,kernel", [
+    (512, 256, 64, False, True),     # tiles: the compiled kernel
+    (512, 64, 64, False, False),     # a 64-step chunk is under one lane tile
+    (512, 256, 48, False, False),    # 48-lane heads do not divide 128 lanes
+    (512, 256, 64, True, False),     # an initial state
+])
+def test_ssd_scan_dispatch_by_shape(monkeypatch, s, chunk, p, init, kernel):
+    """On TPU ``ops.ssd_scan`` runs the kernel where its blocks tile the
+    input and no initial state is given, and the XLA scan otherwise, which
+    agrees with the sequential oracle."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    args = _ssd_inputs(1, s, 4, p, 16)
+    state = jnp.ones((1, 4, 16, p), jnp.float32) * 0.1 if init else None
+    scan = lambda *a: ops.ssd_scan(*a, state, chunk=chunk)
+    assert ("pallas_call" in str(jax.make_jaxpr(scan)(*args))) == kernel
+    if not kernel:
+        y, st = scan(*args)
+        ye, ste = _with_skip(lambda *a: ref.ssd_reference(*a, state), args)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=2e-3, rtol=2e-3)
+        np.testing.assert_allclose(np.asarray(st), np.asarray(ste), atol=2e-3, rtol=2e-3)
+
+
+def test_ssd_plan_needs_whole_chunks():
+    """A sequence that is not a whole number of chunks has no plan; at
+    mamba2's widths a chunk of 256 runs as two sub-chunks of 128 over
+    blocks of sixteen heads, in groups of two."""
+    from repro.kernels.ssd_scan import plan_blocks
+
+    assert plan_blocks((1, 384, 64, 64), 128, 256) is None
+    assert plan_blocks((1, 384, 64, 64), 128, 256, interpret=True) is None
+    assert plan_blocks((8, 2048, 64, 64), 128, 256) == (256, 128, 16, 2, 64, False)
 
 
 @pytest.mark.parametrize("shape", [(4, 100, 256), (3, 384), (2, 7, 512), (1, 128)])

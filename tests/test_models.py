@@ -173,7 +173,7 @@ def test_ssd_gradient_finite_under_strong_decay():
     """Summed log decays past exp's f32 range within one chunk (here 127)
     must not turn the SSD gradient to NaN through the masked upper
     triangle of the decay matrix."""
-    from repro.models.ssm import ssd_chunked
+    from repro.kernels.ref import ssd_chunked
 
     b, s, h, p, n = 1, 128, 2, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 3)

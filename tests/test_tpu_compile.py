@@ -7,6 +7,7 @@ import: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,14 +73,52 @@ def test_decode_attention_compiles_mistral_nemo(one_chip):
         _spec(one_chip, (), jnp.int32))
 
 
-def test_ssd_scan_compiles_mamba2(one_chip):
-    """H 64, P 64, N 128, chunk 256 over a 2048-token sequence."""
+@pytest.mark.parametrize("h,n", [(64, 128), (128, 16)])
+def test_ssd_scan_compiles_mamba2(one_chip, h, n):
+    """The SSD pair, forward and backward, over a 2048-token sequence of
+    batch 8, P 64, chunk 256: at mamba2's H 64, N 128 and at jamba's H·P
+    8192, N 16."""
     f32 = lambda shape: _spec(one_chip, shape, jnp.float32)
-    assert "tpu_custom_call" in _hlo(
-        lambda x, a, d, b, c: ssd_scan_pallas(x, a, d, b, c),
-        _spec(one_chip, (1, 2048, 64, 64)), f32((1, 2048, 64)),
-        f32((1, 2048, 64)), _spec(one_chip, (1, 2048, 128)),
-        _spec(one_chip, (1, 2048, 128)))
+    args = (_spec(one_chip, (8, 2048, h, 64)), f32((8, 2048, h)), f32((8, 2048, h)),
+            _spec(one_chip, (8, 2048, n)), _spec(one_chip, (8, 2048, n)), f32((h,)))
+
+    def loss(*a):
+        y, state = ssd_scan_pallas(*a)
+        return jnp.sum(y) + jnp.sum(state)
+
+    assert "tpu_custom_call" in _hlo(lambda *a: ssd_scan_pallas(*a), *args)
+    assert _hlo(jax.grad(loss, argnums=range(6)), *args).count("tpu_custom_call") == 2
+
+
+def test_mamba2_step_keeps_the_decay_tiles_in_vmem(one_chip, monkeypatch):
+    """The split step at mamba2-1.3b's widths (four layers, split 3/4),
+    traced as on a TPU, runs the SSD pair in the extract and in the tune
+    pass, and no buffer has the (Q, Q, H) = (256, 256, 64) shape of the
+    XLA scan's decay tiles and their autodiff residuals."""
+    from repro.config import HapiConfig, RunConfig, ShapeConfig, TrainConfig
+    from repro.configs import get_config
+    from repro.core.tier_split import plan_tiers
+    from repro.kernels import ops
+    from repro.models.api import build_model
+    from repro.train.steps import build_hapi_train_step, init_train_state
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("mamba2-1.3b"), n_layers=4, vocab_size=512)
+    shape = ShapeConfig("t", "train", 512, 2)
+    hapi = HapiConfig(compress_transfer=True, cos_batch_min=1)
+    rc = RunConfig(model=cfg, shape=shape, hapi=hapi, train=TrainConfig())
+    model = build_model(cfg)
+    plan = plan_tiers(cfg, shape, hapi, local_batch=2)
+    place = lambda tree: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+    state = place(jax.eval_shape(
+        lambda k: init_train_state(model, rc, plan, k), jax.random.PRNGKey(0)))
+    tokens = _spec(one_chip, (2, 512), jnp.int32)
+    hlo = _hlo(build_hapi_train_step(model, rc, plan), state,
+               {"tokens": tokens, "labels": tokens})
+    kernels = re.findall(r"%(\w+)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert kernels.count("ssd_scan_pallas") >= 3     # extract, tune forward, backward
+    assert "256,256,64]" not in hlo
 
 
 def test_int8_train_step_holds_the_kernels(one_chip, monkeypatch):
