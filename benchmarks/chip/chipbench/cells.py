@@ -6,15 +6,23 @@ to one of them sits in a file of its own, found here by name:
 - ``configs/<config>.json`` (the path given in ``BENCHMARK.json``): the
   configuration as it is run, its source, ``reduced``, ``assumed``, the
   deployment it stands for and the plain reference that checks it
-  (``reference`` names ``chipbench/reference/<name>.py``);
+  (``reference`` names ``chipbench/reference/<name>.py``, which gives
+  ``layer``, the block the reference runs, and ``block_flops`` and
+  ``layers_per_block``, what ``chipbench/counts.py`` counts);
 - ``traffic/<traffic>.json``: the mix's parameters; its ``kind`` names
   the general generator ``chipbench/kinds/<kind>.py`` that reads them;
 - ``limits/<cell>.json``: the limits of the numbers that decide
   ``correct``, with the readings they were set from;
-- ``metrics/<metric>.py``: one reader per per-layer metric.
+- ``metrics/<metric>.py``: one reader per per-layer metric;
+- ``small/configs/<config>.json`` and ``small/limits/<cell>.json``: the
+  sizes and limits of the cell at a width the CPU tests can hold
+  (``chipbench/testing.py``), which run every cell of ``BENCHMARK.json``.
 
 So a later cell, configuration, mix or metric is new files and new
-entries, and no edit.
+entries in ``BENCHMARK.json`` (a cell's name also goes into the
+``workloads`` of each per-layer metric it reports), and no edit of a
+file that is there, as long as the program builds the configuration and
+a generator of its ``kind`` exists.
 """
 from __future__ import annotations
 
@@ -27,6 +35,10 @@ from typing import Callable, NamedTuple, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
+
+
+# What a configuration's plain reference module has to give.
+REFERENCE_API = ("layer", "block_flops", "layers_per_block")
 
 
 class Cell(NamedTuple):
@@ -52,7 +64,8 @@ def resolve(name: str, bench: Optional[dict] = None,
             root: pathlib.Path = ROOT) -> Cell:
     """The cell ``name`` with its configuration, traffic and limits
     loaded; ``KeyError`` for an unknown cell, ``FileNotFoundError`` for a
-    missing file."""
+    missing file, ``AttributeError`` for a reference module that lacks a
+    name of ``REFERENCE_API``: all before any set-up."""
     bench = bench if bench is not None else load_benchmark(root)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -61,6 +74,11 @@ def resolve(name: str, bench: Optional[dict] = None,
     configs = {c["name"]: c for c in bench["configs"]}
     with open(root / configs[w["config"]]["file"]) as f:
         config = json.load(f)
+    ref = reference_module(config)
+    missing = [a for a in REFERENCE_API if not hasattr(ref, a)]
+    if missing:
+        raise AttributeError(f"reference {config['reference']!r} of configuration "
+                             f"{w['config']!r} lacks {missing}")
     with open(BENCH_DIR / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     with open(BENCH_DIR / "limits" / f"{name}.json") as f:

@@ -1,6 +1,8 @@
 """Operations and bytes of the split fine-tune step, from shapes alone.
 
-Model FLOPs per sample of sequence length S (a multiply-add is 2):
+Model FLOPs per sample of sequence length S (a multiply-add is 2), in
+scanned blocks, as the program splits the model (``split`` counts
+blocks, not layers):
 
 - the frozen prefix, forward: ``split`` blocks;
 - the trained suffix, forward and backward: 3x its blocks' forward,
@@ -9,58 +11,47 @@ Model FLOPs per sample of sequence length S (a multiply-add is 2):
 - the head: forward and backward (weights and input), 3 x 2 S d V over
   the published (unpadded) vocabulary.
 
-Recomputation under remat is not counted. Attention is causal: a query
-at position t reads t + 1 keys, so the score and value products cost
-2 S^2 H hd in all (each half of 4 S^2 H hd). The SSD mixer is counted as
-the chunked algorithm of arXiv:2405.21060 with chunk Q: within each
-chunk the causal half of C B^T and of its product with x, and per token
-one state update and one state read (2 N H P each).
+Recomputation under remat is not counted. One block is counted by the
+configuration's plain reference module (``reference`` in its file,
+``chipbench/reference/<name>.py``), found by name:
+
+- ``block_flops(c, s) -> (proj, mix, in_proj)``: one block's forward
+  over S tokens; ``proj`` the products with weights, ``mix`` the rest
+  (convolution, scan, attention scores and values), ``in_proj`` the part
+  of ``proj`` that reads the block's input (the projections of its first
+  layer), whose input gradient the first trained block does not need;
+- ``layers_per_block(c)``: how many of the configuration's ``n_layers``
+  one block spans, so that there are ``n_layers // layers_per_block``
+  blocks.
+
+A block of several layers (a hybrid's period) sums its layers. A layer
+with routed experts (MoE) is counted as balanced routing runs it on the
+experts this chip holds: the router and the shared experts in full, and
+the routed experts as ``top_k x E_held / E_routed`` expert MLPs per
+token, each at its published width.
 """
 from __future__ import annotations
 
-
-def _mamba2(c: dict, s: int):
-    """(projection FLOPs, mixer FLOPs, input-projection FLOPs) of one block."""
-    d, n, p = c["d_model"], c["ssm_state"], c["ssm_headdim"]
-    di = c["ssm_expand"] * d
-    h = di // p
-    q = min(c["ssm_chunk"], s)
-    w = c["conv_width"]
-    in_proj = 2 * d * (2 * di + 2 * n + h) * s
-    out_proj = 2 * di * d * s
-    conv = 2 * w * (di + 2 * n) * s
-    intra = (2 * n + 2 * h * p) * (q + 1) / 2 * s     # causal half, per chunk
-    states = 2 * (2 * n * h * p) * s
-    return in_proj + out_proj, conv + intra + states, in_proj
-
-
-def _dense(c: dict, s: int):
-    d, hq, hkv, hd, f = (c["d_model"], c["n_heads"], c["n_kv_heads"],
-                         c["head_dim"], c["d_ff"])
-    qkv = 2 * d * (hq + 2 * hkv) * hd * s
-    out = 2 * hq * hd * d * s
-    mlp = 3 * 2 * d * f * s
-    attn = 2 * s * s * hq * hd                          # causal QK^T and PV
-    return qkv + out + mlp, attn, qkv
-
-
-BLOCKS = {"ssm": _mamba2, "dense": _dense}
+from chipbench import cells
 
 
 def block_flops(c: dict, s: int) -> float:
-    proj, mix, _ = BLOCKS[c["family"]](c, s)
+    """Forward FLOPs of one block of configuration ``c`` over S tokens."""
+    proj, mix, _ = cells.reference_module(c).block_flops(c, s)
     return proj + mix
 
 
 def finetune_flops_per_sample(c: dict, s: int, split: int) -> dict:
-    """Model FLOPs of one sample through the split fine-tune step."""
-    proj, mix, in_proj = BLOCKS[c["family"]](c, s)
+    """Model FLOPs of one sample through the split fine-tune step; the
+    first ``split`` blocks are frozen."""
+    arch = cells.reference_module(c)
+    proj, mix, in_proj = arch.block_flops(c, s)
     fwd = proj + mix
-    n_suffix = c["n_layers"] - split
+    n_blocks = c["n_layers"] // arch.layers_per_block(c)
     head = 2 * s * c["d_model"] * c["vocab_size"]
     out = {
         "prefix": split * fwd,
-        "suffix": 3 * n_suffix * fwd - in_proj,
+        "suffix": 3 * (n_blocks - split) * fwd - in_proj,
         "head": 3 * head,
     }
     out["total"] = sum(out.values())

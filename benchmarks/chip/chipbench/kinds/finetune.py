@@ -86,10 +86,7 @@ class Job:
         step = jax.jit(build_hapi_train_step(model, rc, plan), donate_argnums=(0,))
         first = self._next()
         self.compiled = step.lower(state, first).compile()
-        self.boundary_bytes = sum(
-            math.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(
-                jax.eval_shape(make_extract_fn(model, plan), frozen, first))
-        ) / t["batch"]
+        self.boundary_bytes = self.boundary_bytes_per_sample(frozen, first)
 
         # The checked steps: the window's own call and feed.
         self.check_batches, losses = [], []
@@ -148,6 +145,13 @@ class Job:
         gc.collect()
 
     # -- what the per-layer readers get -----------------------------------
+    def boundary_bytes_per_sample(self, frozen, batch) -> float:
+        """Bytes per sample of the extract's output, from the shapes of
+        the frozen part and a batch (arrays or ``ShapeDtypeStruct``s)."""
+        out = jax.eval_shape(make_extract_fn(self.model, self.plan), frozen, batch)
+        return sum(math.prod(x.shape) * x.dtype.itemsize
+                   for x in jax.tree.leaves(out)) / self.t["batch"]
+
     def counts(self) -> dict:
         t = self.t
         per_sample = counts.finetune_flops_per_sample(

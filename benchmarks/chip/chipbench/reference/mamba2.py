@@ -79,3 +79,28 @@ def layer(lp, h, c: dict, P):
         g = rmsnorm(g.reshape(s, nh * hp), p["norm_scale"].reshape(-1), eps
                     ).reshape(s, nh, hp)
     return h + P.mm("shp,hpd->sd", g, p["w_out"])
+
+
+# -- the count of operations that ``chipbench/counts.py`` composes --------
+def layers_per_block(c: dict) -> int:
+    """Layers of ``n_layers`` that one scanned block spans."""
+    return 1
+
+
+def block_flops(c: dict, s: int):
+    """(projection FLOPs, mixer FLOPs, input-projection FLOPs) of one
+    block's forward over S tokens. The SSD mixer is counted as the
+    chunked algorithm with chunk Q: within each chunk the causal half of
+    C B^T and of its product with x, and per token one state update and
+    one state read (2 N H P each)."""
+    d, n, p = c["d_model"], c["ssm_state"], c["ssm_headdim"]
+    di = c["ssm_expand"] * d
+    h = di // p
+    q = min(c["ssm_chunk"], s)
+    w = c["conv_width"]
+    in_proj = 2 * d * (2 * di + 2 * n + h) * s
+    out_proj = 2 * di * d * s
+    conv = 2 * w * (di + 2 * n) * s
+    intra = (2 * n + 2 * h * p) * (q + 1) / 2 * s     # causal half, per chunk
+    states = 2 * (2 * n * h * p) * s
+    return in_proj + out_proj, conv + intra + states, in_proj

@@ -66,3 +66,23 @@ def layer(lp, h, c: dict, P):
     x = rmsnorm(h, sub["ln_ffn"]["scale"], eps)
     g = jax.nn.silu(P.mm("sd,df->sf", x, m["w_gate"])) * P.mm("sd,df->sf", x, m["w_up"])
     return h + P.mm("sf,fd->sd", g, m["w_down"])
+
+
+# -- the count of operations that ``chipbench/counts.py`` composes --------
+def layers_per_block(c: dict) -> int:
+    """Layers of ``n_layers`` that one scanned block spans."""
+    return 1
+
+
+def block_flops(c: dict, s: int):
+    """(projection FLOPs, mixer FLOPs, input-projection FLOPs) of one
+    block's forward over S tokens. Attention is causal: a query at
+    position t reads t + 1 keys, so the score and value products cost
+    2 S^2 H hd in all (each half of 4 S^2 H hd)."""
+    d, hq, hkv, hd, f = (c["d_model"], c["n_heads"], c["n_kv_heads"],
+                         c["head_dim"], c["d_ff"])
+    qkv = 2 * d * (hq + 2 * hkv) * hd * s
+    out = 2 * hq * hd * d * s
+    mlp = 3 * 2 * d * f * s
+    attn = 2 * s * s * hq * hd                          # causal QK^T and PV
+    return qkv + out + mlp, attn, qkv

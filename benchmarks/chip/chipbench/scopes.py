@@ -15,8 +15,9 @@ instruction name, with no guess from fusion names:
   program's text;
 - ``trace_op_scopes(path)``: instruction -> phase, from the event
   metadata of a trace file's device planes;
-- ``window_scopes(trace)``: the same map for the trace a per-layer
-  reader is handed, from the run's trace directory;
+- ``window_scopes(trace, path)``: the same map for the trace a
+  per-layer reader is handed, from the file it was read from (the
+  reader context's ``trace_path``);
 - ``phase_seconds`` and ``gaps_by_scope``: device self time by phase,
   and idle time by the phases on either side of each gap.
 
@@ -27,10 +28,8 @@ unscoped and the readers report nothing for it.
 from __future__ import annotations
 
 import bisect
-import glob
 import os
 import re
-import tempfile
 from collections import defaultdict
 from typing import Dict, Optional
 
@@ -40,7 +39,6 @@ PREFIX = "hapi."
 UNSCOPED = "<unscoped>"      # an op the trace knows, under no phase
 UNMAPPED = "<unmapped>"      # an op missing from the instruction map
 EDGE = "<edge>"              # the window's start or end, beside a gap
-TRACE_DIRS = "chipbench-trace-*"     # run.py's trace directories
 TF_OP = "tf_op"
 
 _WRAPPED = re.compile(r"^(?:[A-Za-z_]\w*\()+(.*?)\)*$")
@@ -147,19 +145,15 @@ def trace_op_scopes(path: str) -> Dict[str, Optional[str]]:
     return out
 
 
-def window_scopes(trace: xplane.Trace) -> Dict[str, Optional[str]]:
-    """The instruction map of the trace file ``trace`` was read from: the
-    newest ``*.xplane.pb`` under ``run.py``'s trace directories in the
-    temporary directory (they stay until the per-layer readers are done)
-    that names every op of ``trace``. Empty if none does."""
+def window_scopes(trace: xplane.Trace, path: Optional[str]) -> Dict[str, Optional[str]]:
+    """The instruction map of the trace file at ``path``, which ``trace``
+    was read from; empty where there is no such file or it does not
+    name every op of ``trace``."""
+    if not path or not os.path.exists(path):
+        return {}
     heads = {xplane.short_name(o.name).split(" ")[0] for o in trace.ops}
-    pattern = os.path.join(tempfile.gettempdir(), TRACE_DIRS, "**", "*.xplane.pb")
-    for path in sorted(glob.glob(pattern, recursive=True), key=os.path.getmtime,
-                       reverse=True):
-        scopes = trace_op_scopes(path)
-        if heads and heads <= scopes.keys():
-            return scopes
-    return {}
+    scopes = trace_op_scopes(path)
+    return scopes if heads and heads <= scopes.keys() else {}
 
 
 # -- time by phase ----------------------------------------------------------
@@ -214,7 +208,7 @@ def phase_ms_per_step(ctx: dict, phase: str) -> Optional[float]:
     trace, steps = ctx["trace"], ctx["steps"]
     if not steps or not trace.n_devices:
         return None
-    scopes = window_scopes(trace)
+    scopes = window_scopes(trace, ctx.get("trace_path"))
     if not any(scopes.values()):
         return None
     secs = phase_seconds(trace, ctx["lo"], ctx["hi"], scopes).get(phase, 0.0)

@@ -69,11 +69,13 @@ def per_layer(cell, out: dict, trace_dir: str, peaks: dict):
     """Read the trace and every per-layer metric of the cell."""
     from chipbench import cells, xplane
 
-    tr = xplane.load(xplane.find_xplane(trace_dir))
+    path = xplane.find_xplane(trace_dir)
+    tr = xplane.load(path)
     lo, hi = xplane.window(tr)
     busy = xplane.busy_ns(tr, lo, hi) * 1e-9
-    ctx = dict(out["counts"], trace=tr, lo=lo, hi=hi, window_s=(hi - lo) * 1e-9,
-               busy_s=busy, steps=out["window"]["steps"], peaks=peaks)
+    ctx = dict(out["counts"], trace=tr, trace_path=path, lo=lo, hi=hi,
+               window_s=(hi - lo) * 1e-9, busy_s=busy, steps=out["window"]["steps"],
+               peaks=peaks)
     metrics = {}
     for m in cell.per_layer:
         value = cells.metric_reader(m["name"])(ctx)

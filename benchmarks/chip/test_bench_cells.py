@@ -33,6 +33,24 @@ def test_cell_resolves_by_name(cell):
         assert m["moves"] in reported
 
 
+@pytest.mark.parametrize("cell,config", [(w["name"], w["config"])
+                                         for w in BENCH["workloads"]])
+def test_cell_has_its_small_files(cell, config):
+    """The CPU tests run every cell at a small size from files found by
+    name: the sizes of its configuration and the limits of the cell."""
+    from chipbench import testing
+
+    c = cells.resolve(cell, BENCH)
+    small = testing.small_config(config)
+    assert small and set(small) <= set(c.config)
+    limits = testing.small_limits(cell)
+    assert set(limits["numbers"]) <= {"loss_gap", "grad_gap", "grad_gap_median",
+                                      "update_gap"}
+    for spec in limits["numbers"].values():
+        assert spec["lower"] < spec["limit"] < spec["upper"]
+    assert testing.small_cell(cell).limits == limits
+
+
 def test_unknown_cell_is_refused():
     with pytest.raises(KeyError):
         cells.resolve("no-such-cell", BENCH)

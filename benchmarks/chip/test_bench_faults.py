@@ -12,7 +12,7 @@ import pytest
 from chipbench import cells, compare, testing
 from chipbench.kinds import finetune
 
-CELLS = ["mamba2-ft-2k", "nemo8l-ft-2k"]
+CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]]
 SEED = 3_000_000_019          # above 2**31, as the driver's seeds are
 
 

@@ -12,6 +12,8 @@ import pytest
 from chipbench import cells, testing, weights
 from chipbench.reference.lm import LMReference
 
+BENCH = cells.load_benchmark()
+
 
 def _setup(cell_name):
     cell = testing.small_cell(cell_name, seq_len=64, batch=2)
@@ -28,7 +30,7 @@ def _setup(cell_name):
     return model, split, frozen, trainable, toks, ref
 
 
-@pytest.fixture(scope="module", params=["mamba2-ft-2k", "nemo8l-ft-2k"])
+@pytest.fixture(scope="module", params=[w["name"] for w in BENCH["workloads"]])
 def setup(request):
     with jax.default_matmul_precision("highest"):
         yield _setup(request.param)
@@ -82,9 +84,11 @@ def test_seed_key_takes_large_seeds():
         weights.seed_key(-1)
 
 
-def test_configs_state_their_departures():
-    for cfg in ("mamba2-1.3b", "mistral-nemo-12b-8l"):
-        c = testing.small_cell({"mamba2-1.3b": "mamba2-ft-2k",
-                                "mistral-nemo-12b-8l": "nemo8l-ft-2k"}[cfg]).config
-        assert c["departures"]["embed_times_sqrt_d"] is True
-        assert dataclasses.is_dataclass(cells.model_config(c))
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_configs_state_their_departures(config):
+    """The program multiplies the embedding by sqrt(d_model) in every
+    family, so every configuration states it, at its small size too."""
+    cell = next(w["name"] for w in BENCH["workloads"] if w["config"] == config)
+    c = testing.small_cell(cell).config
+    assert c["departures"]["embed_times_sqrt_d"] is True
+    assert dataclasses.is_dataclass(cells.model_config(c))

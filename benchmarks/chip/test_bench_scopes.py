@@ -5,8 +5,6 @@ metadata of a trace file written from a text proto, the three readers,
 and two small traces recorded on a TPU v5e as ``record_trace.py``
 records them: the program before it named its phases
 (``testdata/tiny.xplane.pb``) and after (``tiny-scoped.xplane.pb``)."""
-import shutil
-
 import pytest
 
 from chipbench import cells, scopes, xplane
@@ -163,10 +161,9 @@ OP_NAMES = ["jit(train_step)/while/body/hapi.extract/while/body/dot_general:",
 
 
 @pytest.fixture
-def trace_dir(tmp_path, monkeypatch):
-    """A run's trace directory as ``run.py`` makes it, in a temporary
-    directory of the test's own."""
-    monkeypatch.setattr(scopes.tempfile, "tempdir", str(tmp_path))
+def trace_dir(tmp_path):
+    """A run's trace directory as the profiler lays it out under the one
+    ``run.py`` makes."""
     return tmp_path / "chipbench-trace-abc" / "plugins" / "profile" / "t"
 
 
@@ -175,9 +172,9 @@ def test_trace_file_metadata_and_readers(trace_dir):
     _write_trace(path, _synthetic().ops, OP_NAMES)
     tr = xplane.load(str(path))
     assert scopes.trace_op_scopes(str(path)) == dict(SCOPES, **{"%mystery.8": None})
-    assert scopes.window_scopes(tr) == scopes.trace_op_scopes(str(path))
+    assert scopes.window_scopes(tr, str(path)) == scopes.trace_op_scopes(str(path))
     lo, hi = xplane.window(tr)
-    ctx = dict(trace=tr, lo=lo, hi=hi, steps=2)
+    ctx = dict(trace=tr, trace_path=str(path), lo=lo, hi=hi, steps=2)
     got = {m: cells.metric_reader(m)(ctx)
            for m in ("extract_ms.train", "tune_ms.train", "adamw_ms.train")}
     assert got == pytest.approx({"extract_ms.train": 160e-6 / 2,
@@ -186,52 +183,54 @@ def test_trace_file_metadata_and_readers(trace_dir):
 
 
 def test_readers_read_nothing_without_phases(trace_dir):
-    """A program that names no phases, or a trace whose file is gone."""
+    """A program that names no phases, a trace whose file is gone, or a
+    reader context without the file's path."""
     path = trace_dir / "host.xplane.pb"
     _write_trace(path, _synthetic().ops, [None] * 8)
     tr = xplane.load(str(path))
     lo, hi = xplane.window(tr)
-    ctx = dict(trace=tr, lo=lo, hi=hi, steps=2)
+    ctx = dict(trace=tr, trace_path=str(path), lo=lo, hi=hi, steps=2)
     assert scopes.phase_ms_per_step(ctx, "hapi.extract") is None
+    _write_trace(trace_dir.parent / "u" / "host.xplane.pb", _synthetic().ops, OP_NAMES)
+    ctx["trace_path"] = str(trace_dir.parent / "u" / "host.xplane.pb")
+    assert cells.metric_reader("tune_ms.train")(ctx) == pytest.approx(70e-6 / 2)
+    assert cells.metric_reader("tune_ms.train")(dict(ctx, trace_path=None)) is None
     path.unlink()
-    assert scopes.window_scopes(tr) == {}
-    assert cells.metric_reader("tune_ms.train")(ctx) is None
+    assert scopes.window_scopes(tr, str(path)) == {}
+    assert cells.metric_reader("tune_ms.train")(dict(ctx, trace_path=str(path))) is None
 
 
 def test_another_runs_trace_is_not_read(trace_dir):
-    """A stale trace directory that does not hold this window's ops."""
-    _write_trace(trace_dir / "host.xplane.pb", _synthetic().ops[:3], OP_NAMES[:3])
-    assert scopes.window_scopes(_synthetic()) == {}
+    """A trace file that does not hold this window's ops."""
+    path = trace_dir / "host.xplane.pb"
+    _write_trace(path, _synthetic().ops[:3], OP_NAMES[:3])
+    assert scopes.window_scopes(_synthetic(), str(path)) == {}
 
 
-def test_recorded_trace_maps_every_op_and_names_no_phase(trace_dir):
+def test_recorded_trace_maps_every_op_and_names_no_phase():
     """The small TPU v5e trace, recorded from the program before it named
     its phases: every op is in the metadata, under no phase."""
     if not RECORDED.exists():
         pytest.fail(f"missing {RECORDED}")
-    trace_dir.mkdir(parents=True)
-    shutil.copy(RECORDED, trace_dir / "host.xplane.pb")
     tr = xplane.load(str(RECORDED))
-    m = scopes.window_scopes(tr)
+    m = scopes.window_scopes(tr, str(RECORDED))
     assert m and not any(m.values())
     lo, hi = xplane.window(tr)
     got = scopes.phase_seconds(tr, lo, hi, m)
     assert set(got) == {scopes.UNSCOPED}
     assert got[scopes.UNSCOPED] == pytest.approx(xplane.busy_ns(tr, lo, hi) * 1e-9)
-    assert cells.metric_reader("extract_ms.train")(dict(trace=tr, lo=lo, hi=hi,
-                                                        steps=2)) is None
+    assert cells.metric_reader("extract_ms.train")(dict(
+        trace=tr, trace_path=str(RECORDED), lo=lo, hi=hi, steps=2)) is None
 
 
-def test_recorded_scoped_trace_reads_every_phase(trace_dir):
+def test_recorded_scoped_trace_reads_every_phase():
     """The small TPU v5e trace of the program with its phases named: each
     phase holds time, the phases and the unscoped rest add up to the busy
     time, and the readers agree with ``phase_seconds``."""
     if not SCOPED.exists():
         pytest.fail(f"missing {SCOPED}")
-    trace_dir.mkdir(parents=True)
-    shutil.copy(SCOPED, trace_dir / "host.xplane.pb")
     tr = xplane.load(str(SCOPED))
-    m = scopes.window_scopes(tr)
+    m = scopes.window_scopes(tr, str(SCOPED))
     assert set(m.values()) == PHASES | {None}
     lo, hi = xplane.window(tr)
     got = scopes.phase_seconds(tr, lo, hi, m)
@@ -241,7 +240,7 @@ def test_recorded_scoped_trace_reads_every_phase(trace_dir):
     assert sum(got.values()) == pytest.approx(busy)
     assert got[scopes.UNSCOPED] < 0.5 * busy
     steps = sum(s.name == "bench.step_dispatch" for s in tr.spans)
-    ctx = dict(trace=tr, lo=lo, hi=hi, steps=steps)
+    ctx = dict(trace=tr, trace_path=str(SCOPED), lo=lo, hi=hi, steps=steps)
     for metric, phase in (("extract_ms.train", "hapi.extract"), ("tune_ms.train", "hapi.tune"),
                           ("adamw_ms.train", "hapi.adamw")):
         assert cells.metric_reader(metric)(ctx) == pytest.approx(1e3 * got[phase] / steps)
