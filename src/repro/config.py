@@ -37,6 +37,7 @@ HW = HardwareSpec()
 # Model configuration
 # ---------------------------------------------------------------------------
 FAMILIES = ("dense", "moe", "ssm", "hybrid", "encdec", "vlm")
+LAYER_KINDS = "M*E"      # letters of ``ModelConfig.layer_pattern``
 
 
 @dataclass(frozen=True)
@@ -59,11 +60,15 @@ class ModelConfig:
     sliding_window: Optional[int] = None     # gemma2 local layers (4096)
     local_global_period: int = 0             # gemma2: 2 -> alternate local/global
     rope_theta: float = 1e4
+    use_rope: bool = True                    # nemotron_h: attention without positions
 
     # --- mixture of experts -------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0                       # routed experts (the router's width)
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25            # moe_apply's cap; the expert layer drops nothing
+    routed_scaling: float = 1.0              # expert layer: gate weights times this
+    experts_held: int = 0                    # expert layer: experts [0, held) on this chip; 0 = all
+    shared_expert_ff: int = 0                # expert layer: width of the shared expert
 
     # --- state-space (mamba2 / jamba) ----------------------------------------
     ssm_state: int = 0
@@ -71,6 +76,14 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_chunk: int = 256
     conv_width: int = 4
+    ssm_heads: int = 0                       # 0 -> ssm_expand * d_model / ssm_headdim
+    ssm_groups: int = 1                      # B/C groups; head h reads group h // (H / G)
+    ssm_norm_group: int = 0                  # lanes of one gated-norm group; 0 -> one head
+
+    # --- layer pattern (nemotron_h) ---------------------------------------------
+    # One block's layers, one letter each: M Mamba-2, * attention, E expert
+    # layer; every layer is one pre-norm residual branch.
+    layer_pattern: str = ""
 
     # --- hybrid (jamba) -------------------------------------------------------
     attn_period: int = 0                     # 1 attention layer per period
@@ -98,6 +111,7 @@ class ModelConfig:
     # -----------------------------------------------------------------------
     def __post_init__(self):
         assert self.family in FAMILIES, self.family
+        assert set(self.layer_pattern) <= set(LAYER_KINDS), self.layer_pattern
 
     @property
     def hdim(self) -> int:
@@ -111,11 +125,23 @@ class ModelConfig:
     @property
     def d_inner(self) -> int:
         """SSM inner width."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_nheads(self) -> int:
         return self.d_inner // self.ssm_headdim
+
+    @property
+    def ssm_norm_lanes(self) -> int:
+        """Lanes of one group of the SSD mixer's gated RMSNorm."""
+        return self.ssm_norm_group or self.ssm_headdim
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts held on this chip (the expert layer)."""
+        return self.experts_held or self.n_experts
 
     # --- block structure (scan units == split-candidate granularity) --------
     @property
@@ -123,6 +149,8 @@ class ModelConfig:
         """Number of scan units. Split candidates live at block boundaries."""
         if self.family == "encdec":
             return self.n_enc_layers  # splitting happens in the encoder prefix
+        if self.layer_pattern:
+            return self.n_layers // len(self.layer_pattern)
         if self.local_global_period:
             return self.n_layers // self.local_global_period
         if self.attn_period:
@@ -131,6 +159,8 @@ class ModelConfig:
 
     @property
     def layers_per_block(self) -> int:
+        if self.layer_pattern:
+            return len(self.layer_pattern)
         if self.local_global_period:
             return self.local_global_period
         if self.attn_period:
@@ -162,17 +192,41 @@ class ModelConfig:
         return n * per_expert + router
 
     def _ssm_params(self) -> int:
-        di, ns, nh = self.d_inner, self.ssm_state, self.ssm_nheads
+        di, nh = self.d_inner, self.ssm_nheads
+        ns = self.ssm_groups * self.ssm_state
         in_proj = self.d_model * (2 * di + 2 * ns + nh)  # z, x, B, C, dt
         conv = self.conv_width * (di + 2 * ns)
         out = di * self.d_model
         extra = nh * 3  # A_log, D, dt_bias
         return in_proj + conv + out + extra
 
+    def _expert_layer_params(self, active: bool) -> int:
+        """The expert layer: the router over all routed experts and its
+        selection bias, the held relu^2 experts (up and down), and the
+        shared expert; ``active``: the experts one token runs."""
+        d = self.d_model
+        routed = (self.top_k if active else self.n_held) * 2 * d * self.d_ff
+        return d * self.n_experts + self.n_experts + routed + 2 * d * self.shared_expert_ff
+
+    def _pattern_layer_params(self, kind: str, active: bool) -> int:
+        """One layer of ``layer_pattern``, exactly as ``model.init`` makes
+        it: the pre-norm scale and the one mixer."""
+        if kind == "M":
+            di, ns = self.d_inner, self.ssm_groups * self.ssm_state
+            conv_bias = di + 2 * ns
+            mixer = self._ssm_params() + conv_bias + di       # + the norm's scale
+        elif kind == "*":
+            mixer = self._attn_params()
+        else:
+            mixer = self._expert_layer_params(active)
+        return self.d_model + mixer
+
     def block_params(self, active_only: bool = False) -> int:
         """Params of one scan unit (all sublayers inside it)."""
         d = self.d_model
         norm = 2 * d  # two norms per sublayer (approx, pre-norm archs)
+        if self.layer_pattern:
+            return sum(self._pattern_layer_params(k, active_only) for k in self.layer_pattern)
         if self.local_global_period:
             # gemma2: one block == one (local, global) pair.
             per = self._attn_params() + self._dense_ffn_params() + norm

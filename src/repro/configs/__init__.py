@@ -1,4 +1,5 @@
-"""Architecture registry — the 10 assigned archs (+ paper vision models).
+"""Architecture registry — the 10 assigned archs, Nemotron 3 Nano (+ paper
+vision models).
 
 ``get_config(arch_id)`` returns the exact published configuration;
 ``get_smoke_config(arch_id)`` returns a reduced same-family variant for
@@ -22,6 +23,7 @@ from repro.configs import (  # noqa: E402
     llava_next_mistral_7b,
     whisper_small,
     jamba_v0_1_52b,
+    nemotron3_nano_30b_a3b,
 )
 
 _MODULES = {
@@ -35,6 +37,7 @@ _MODULES = {
     "llava-next-mistral-7b": llava_next_mistral_7b,
     "whisper-small": whisper_small,
     "jamba-v0.1-52b": jamba_v0_1_52b,
+    "nemotron3-nano-30b-a3b": nemotron3_nano_30b_a3b,
 }
 
 ARCH_IDS: List[str] = list(_MODULES)
@@ -62,7 +65,16 @@ def get_smoke_config(arch_id: str) -> ModelConfig:
         param_dtype="float32",
         compute_dtype="float32",
     )
-    if cfg.family == "moe":
+    if cfg.layer_pattern:
+        # Two periods of the first seven layers: 2 B/C groups of 4 heads,
+        # 4 of 8 routed experts held.
+        kw.update(
+            n_layers=14, layer_pattern=cfg.layer_pattern[:7], n_experts=8,
+            experts_held=4, top_k=2, d_ff=32, shared_expert_ff=64, ssm_state=16,
+            ssm_heads=8, ssm_headdim=16, ssm_groups=2, ssm_norm_group=64,
+            ssm_chunk=16,
+        )
+    elif cfg.family == "moe":
         kw.update(n_layers=2, n_experts=8, top_k=2, capacity_factor=8.0)
     elif cfg.family == "ssm":
         kw.update(n_layers=2, ssm_state=16, ssm_headdim=16, ssm_chunk=16)

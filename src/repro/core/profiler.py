@@ -82,11 +82,24 @@ def _moe_flops(cfg: ModelConfig, s: int) -> float:
     return router + expert
 
 
+def _experts_flops(cfg: ModelConfig, s: int) -> float:
+    """The expert layer under balanced routing: the router over all routed
+    experts, top_k x E_held / E_routed relu^2 experts (up and down) a
+    token on the experts this chip holds, and the shared expert."""
+    d = cfg.d_model
+    router = 2 * s * d * cfg.n_experts
+    routed = 2 * s * cfg.top_k * cfg.n_held / cfg.n_experts * 2 * d * cfg.d_ff
+    shared = 2 * s * 2 * d * cfg.shared_expert_ff
+    return router + routed + shared
+
+
 def _ssm_flops(cfg: ModelConfig, s: int) -> float:
-    d, di, n, h, p = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_headdim
+    d, di, h, p = cfg.d_model, cfg.d_inner, cfg.ssm_nheads, cfg.ssm_headdim
+    n = cfg.ssm_state
+    gn = cfg.ssm_groups * n
     q = min(cfg.ssm_chunk, s)
-    proj = 2 * s * d * (2 * di + 2 * n + h) + 2 * s * di * d
-    conv = 2 * s * cfg.conv_width * (di + 2 * n)
+    proj = 2 * s * d * (2 * di + 2 * gn + h) + 2 * s * di * d
+    conv = 2 * s * cfg.conv_width * (di + 2 * gn)
     # chunked SSD: CB scores (Q*N), diag (Q*H*P... dominated by Q terms),
     # state in/out (N*P*H) per token.
     ssd = 2 * s * (q * n + q * h + q * h * p) + 4 * s * n * p * h
@@ -94,16 +107,19 @@ def _ssm_flops(cfg: ModelConfig, s: int) -> float:
 
 
 def sublayer_flops(cfg: ModelConfig, sub: SubLayer, s: int) -> float:
+    f = 0.0
     if sub.mixer == "attn":
         f = _attn_flops(cfg, s, None)
     elif sub.mixer == "attn_local":
         f = _attn_flops(cfg, s, cfg.sliding_window)
-    else:
+    elif sub.mixer == "mamba":
         f = _ssm_flops(cfg, s)
     if sub.ffn == "mlp":
         f += _mlp_flops(cfg, s)
     elif sub.ffn == "moe":
         f += _moe_flops(cfg, s)
+    elif sub.ffn == "experts":
+        f += _experts_flops(cfg, s)
     return f
 
 

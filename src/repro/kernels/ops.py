@@ -1,9 +1,10 @@
 """Public wrappers for the Pallas kernels, with backend dispatch.
 
-The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``) and the
-SSD scan follow the platform: on TPU they run the compiled Pallas
-kernels, elsewhere the pure-XLA references (the SSD scan also where its
-blocks cannot tile the input). Flash and decode attention sit off the
+The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``), the
+SSD scan and the grouped matmul of the expert layer follow the platform:
+on TPU they run the compiled Pallas kernels, elsewhere the pure-XLA
+references (the SSD scan and the grouped matmul also where their blocks
+cannot tile the input). Flash and decode attention sit off the
 model path; ``use_pallas(True)`` routes them to their Pallas kernels, and
 ``use_pallas(True, interpret=True)`` runs those kernels in the Pallas
 interpreter off-TPU (tests only). Every wrapper reads the switch when it
@@ -105,16 +106,55 @@ def decode_attention(q, k_cache, v_cache, length, *, softcap=None):
 
 def ssd_scan(x, dtA, dt, B_, C_, D, init_state=None, *, chunk: int = 256):
     """Chunked SSD scan with its D skip: y = SSD(x) + D x in x's dtype,
-    (B, S, H, P), and the final state (B, H, N, P) f32. The compiled
+    (B, S, H, P), and the final state (B, H, N, P) f32; B and C are
+    (B, S, G, N). The compiled
     Pallas pair on TPU, ``ref.ssd_chunked`` elsewhere, and on TPU too
     where an initial state is given or the blocks cannot tile the input
     (``ssd_scan.plan_blocks``)."""
     if (on_tpu() and init_state is None
-            and sk.plan_blocks(x.shape, B_.shape[-1], chunk) is not None):
+            and sk.plan_blocks(x.shape, B_.shape[-1], chunk, groups=B_.shape[-2]) is not None):
         return sk.ssd_scan_pallas(x, dtA, dt, B_, C_, D, chunk=chunk)
     y, state = ref.ssd_chunked(x, dtA, dt, B_, C_, init_state, chunk)
     y = y + D[None, None, :, None] * x.astype(jnp.float32)
     return y.astype(x.dtype), state
+
+
+GMM_ROWS = 512              # rows of one grouped-matmul tile
+GMM_LANES = 384            # target width of its K and N tiles
+
+
+def _gmm_tile(dim: int) -> int:
+    """A K or N tile of the grouped matmul: ``GMM_LANES`` where it divides
+    ``dim``, else the largest multiple of 128 under it that does, else the
+    whole dim (Mosaic's blocks are multiples of 128 lanes or whole)."""
+    for t in range(min(GMM_LANES, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """(rows, K, N) tile of megablox's grouped matmul for an (m, k) by
+    (k, n) problem; each matmul of its backward asks again."""
+    return min(GMM_ROWS, m), _gmm_tile(k), _gmm_tile(n)
+
+
+def grouped_matmul(x, w, group_sizes, *, interpret: bool = False):
+    """Rows of ``x`` (M, K), sorted by group, times their group's matrix of
+    ``w`` (G, K, N): group g owns the ``group_sizes[g]`` rows after the
+    groups before it, and rows past all groups come out undefined. On TPU
+    (or ``interpret``) the Pallas grouped matmul that JAX ships
+    (megablox: group offsets prefetched as scalars, only the row tiles
+    that hold a group's rows visited, forward and backward), where the
+    row tiles divide M; elsewhere XLA's ``ragged_dot``. Not XLA's on TPU:
+    its TPU rewrite renames the op and drops the named scopes around it."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    m = x.shape[0]
+    if (on_tpu() or interpret) and m % gmm_tiling(*x.shape, w.shape[-1])[0] == 0:
+        return megablox.gmm(x, w, group_sizes, x.dtype, gmm_tiling, None, None, False,
+                            interpret)
+    return jax.lax.ragged_dot(x, w, group_sizes)
 
 
 def quantize_int8(x, tile: int = WIRE_TILE):

@@ -77,22 +77,24 @@ def ssd_reference(
     x: jnp.ndarray,          # (B, S, H, P)
     dtA: jnp.ndarray,        # (B, S, H) log decay
     dt: jnp.ndarray,         # (B, S, H) input scale
-    B_: jnp.ndarray,         # (B, S, N)
-    C_: jnp.ndarray,         # (B, S, N)
+    B_: jnp.ndarray,         # (B, S, G, N)
+    C_: jnp.ndarray,         # (B, S, G, N)
     init_state: Optional[jnp.ndarray] = None,  # (B, H, N, P)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Head h reads B/C group h // (H / G)."""
     b, s, h, p = x.shape
-    n = B_.shape[-1]
+    g, n = B_.shape[-2:]
     if init_state is None:
         init_state = jnp.zeros((b, h, n, p), jnp.float32)
 
     def step(state, inp):
         xt, at, dtt, bt, ct = inp
         a = jnp.exp(at)[:, :, None, None]                        # (B,H,1,1)
-        upd = jnp.einsum("bn,bhp->bhnp", bt, xt * dtt[..., None])
+        xg = (xt * dtt[..., None]).reshape(b, g, h // g, p)
+        upd = jnp.einsum("bgn,bghp->bghnp", bt, xg).reshape(b, h, n, p)
         state = state * a + upd
-        y = jnp.einsum("bn,bhnp->bhp", ct, state)
-        return state, y
+        y = jnp.einsum("bgn,bghnp->bghp", ct, state.reshape(b, g, h // g, n, p))
+        return state, y.reshape(b, h, p)
 
     xs = (
         jnp.moveaxis(x.astype(jnp.float32), 1, 0),
@@ -112,11 +114,13 @@ def ssd_chunked(x, dtA, dtx_scale, B, C, init_state=None, chunk: int = 256):
     x:   (B, S, H, P)    head inputs
     dtA: (B, S, H)       log-decay per step (= dt * A, A < 0)
     dtx_scale: (B, S, H) dt multiplier applied to inputs
-    B,C: (B, S, N)       input/output projections (single group)
+    B,C: (B, S, G, N)    input/output projections in G groups; head h
+                         reads group h // (H / G)
     Returns (y (B,S,H,P), final_state (B,H,N,P)).
     """
     b, s, h, p = x.shape
-    n = B.shape[-1]
+    g, n = B.shape[-2:]
+    hg = h // g
     q = min(chunk, s)
     assert s % q == 0
     nc = s // q
@@ -126,12 +130,13 @@ def ssd_chunked(x, dtA, dtx_scale, B, C, init_state=None, chunk: int = 256):
     xc = jnp.moveaxis(x.reshape(b, nc, q, h, p), 1, 0)
     dtAc = jnp.moveaxis(dtA.reshape(b, nc, q, h).astype(jnp.float32), 1, 0)
     dtsc = jnp.moveaxis(dtx_scale.reshape(b, nc, q, h).astype(jnp.float32), 1, 0)
-    Bc = jnp.moveaxis(B.reshape(b, nc, q, n), 1, 0)
-    Cc = jnp.moveaxis(C.reshape(b, nc, q, n), 1, 0)
+    Bc = jnp.moveaxis(B.reshape(b, nc, q, g, n), 1, 0)
+    Cc = jnp.moveaxis(C.reshape(b, nc, q, g, n), 1, 0)
 
     if init_state is None:
         init_state = jnp.zeros((b, h, n, p), jnp.float32)
     tri = jnp.tril(jnp.ones((q, q), bool))
+    per_group = lambda a: a.reshape(*a.shape[:-1], g, hg)          # (..., H) -> (..., G, H/G)
 
     def chunk_step(state, inp):
         xk, ak, dk, bk, ck = inp                           # (B,Q,...)
@@ -143,22 +148,25 @@ def ssd_chunked(x, dtA, dtx_scale, B, C, init_state=None, chunk: int = 256):
         seg = jnp.where(tri[None, :, :, None],
                         cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
         Lmat = jnp.exp(seg)                                # (B,Q,Q,H)
-        cb = jnp.einsum("bqn,bkn->bqk", ck, bk, preferred_element_type=jnp.float32)
-        scores = cb[..., None] * Lmat                      # (B,Q,Q,H)
+        cb = jnp.einsum("bqgn,bkgn->bqkg", ck, bk, preferred_element_type=jnp.float32)
+        scores = per_group(Lmat) * cb[..., None]           # (B,Q,Q,G,H/G)
         xs = xk.astype(jnp.float32) * dk[..., None]        # dt-scaled inputs
-        y_diag = jnp.einsum("bqkh,bkhp->bqhp", scores, xs)
+        y_diag = jnp.einsum("bqkgh,bkghp->bqghp", scores,
+                            xs.reshape(b, q, g, hg, p))
         # Carried-state contribution.
         decay_in = jnp.exp(cum)                            # (B,Q,H)
+        state_g = state.reshape(b, g, hg, n, p)
         y_off = jnp.einsum(
-            "bqn,bhnp,bqh->bqhp", ck.astype(jnp.float32), state, decay_in
+            "bqgn,bghnp,bqgh->bqghp", ck.astype(jnp.float32), state_g, per_group(decay_in)
         )
         # State update.
         decay_to_end = jnp.exp(cum[:, -1:, :] - cum)       # (B,Q,H)
         s_chunk = jnp.einsum(
-            "bqn,bqh,bqhp->bhnp", bk.astype(jnp.float32), decay_to_end, xs
-        )
+            "bqgn,bqgh,bqghp->bghnp", bk.astype(jnp.float32), per_group(decay_to_end),
+            xs.reshape(b, q, g, hg, p)
+        ).reshape(b, h, n, p)
         new_state = state * jnp.exp(cum[:, -1, :])[:, :, None, None] + s_chunk
-        return new_state, (y_diag + y_off)
+        return new_state, (y_diag + y_off).reshape(b, q, h, p)
 
     final_state, ys = jax.lax.scan(chunk_step, init_state, (xc, dtAc, dtsc, Bc, Cc))
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p)

@@ -5,13 +5,20 @@ builds, for every head, the decay tile L[i, j] = exp(cum_i - cum_j)
 (i >= j) and the scores C B^T o L in VMEM, adds the carried state's
 contribution and the D skip, and updates the (N, P) state, also in VMEM.
 Only the chunk's inputs and outputs and the f32 state at the start of
-each chunk (the backward's residual, ``(B, n_chunks, H*P/G/P, G*P, N)``)
+each chunk (the backward's residual, ``(B, n_chunks, H/g, g*P, N)``)
 reach HBM; the XLA twin (``ref.ssd_chunked``) writes the (Q, Q, H) tiles
 and, under autodiff, stacks them per chunk. The backward sweeps the
 chunks in reverse, carries the state's cotangent in VMEM, rebuilds each
 decay tile from the saved inputs and emits dx, d(cum), d(dt), dD, dB and
-dC; dB and dC are head-shared, so they accumulate over all head blocks of
-a chunk. ``jax.custom_vjp`` joins the pair.
+dC; dB and dC are shared by the heads of a group, so they accumulate over
+all head blocks of a chunk. ``jax.custom_vjp`` joins the pair.
+
+B and C come in G groups, head h reading group h // (H/G). The groups
+are folded into the grid's batch axis: after the sequence-minor
+transposes, x's ``(B, H*P, S)`` is ``(B*G, H/G*P, S)`` and B's
+``(B, G*N, S)`` is ``(B*G, N, S)`` with no copy, so each folded row is a
+one-group problem of H/G heads; only D is indexed by the group. At one
+group nothing is folded.
 
 Each grid chunk is swept in sub-chunks of 128 steps, one lane tile: the
 SSD result does not depend on the chunk length, and a (128, 128) decay
@@ -24,7 +31,8 @@ norm and the out-projection around the mixer keep them in, so XLA writes
 no relayout of them to HBM, and the kernels transpose no (Q, H*P) tile.
 In VMEM a head is P rows and a step one lane; the state of a head is
 (P, N). The state's cotangent is also kept as (N, P), so that the
-gradients of B and C are plain matmuls over a group of G = 128/P heads.
+gradients of B and C are plain matmuls over a lane group of g = 128/P
+heads.
 Mosaic has no cumsum: the sub-chunk-local cumulative sum of the log
 decays stays an XLA op on its (B, H, S) array.
 
@@ -57,17 +65,22 @@ class Plan(NamedTuple):
     q: int          # grid chunk length
     sq: int         # sub-chunk length
     hb: int         # heads per block
-    g: int          # heads per group of 128 rows
+    g: int          # heads per lane group of 128 rows
     p: int          # head width
     interpret: bool
 
 
-def plan_blocks(x_shape, n: int, chunk: int, interpret: bool = False) -> Optional[Plan]:
-    """Block sizes for x of ``(B, S, H, P)`` and state width ``n``, or None
+def plan_blocks(x_shape, n: int, chunk: int, interpret: bool = False,
+                groups: int = 1) -> Optional[Plan]:
+    """Block sizes for x of ``(B, S, H, P)`` and state width ``n`` with
+    B/C in ``groups`` groups (planned per group of H/G heads), or None
     where the blocks cannot tile the input (the caller then runs the XLA
     twin). Compiled, a block's last two dims are multiples of (8, 128)
     (16 rows for bf16) or whole array dims; interpreted, any size goes."""
     _, s, h, p = x_shape
+    if h % groups:
+        return None
+    h //= groups
     q = min(chunk, s)
     tiled = lambda size, unit, full: interpret or size % unit == 0 or size == full
     if s % q or not (tiled(q, LANES, s) and tiled(q, 16, s)):
@@ -101,7 +114,7 @@ def _t_dot(a, b):
 
 
 def _lanes_of(plan: Plan, rows):
-    """(1, G*P) from G (1, width) rows that each hold one value: lane l
+    """(1, g*P) from g (1, width) rows that each hold one value: lane l
     takes head l // P's."""
     gw = plan.g * plan.p
     head = jax.lax.broadcasted_iota(jnp.int32, (1, gw), 1) // plan.p
@@ -123,13 +136,19 @@ class _Head(NamedTuple):
     d: jnp.ndarray          # () the head's D
 
 
-def _head(plan: Plan, x_ref, cum_ref, dt_ref, d_ref, h: int, r: slice) -> _Head:
+def _head(plan: Plan, x_ref, cum_ref, dt_ref, d_ref, h: int, r: slice,
+          d_group=None) -> _Head:
+    """``d_group``: (G, H/G) where the grid's batch axis folds G groups,
+    so that a row's D is its group's."""
     sq = plan.sq
     cum = cum_ref[0, h:h + 1, r]
     dt = dt_ref[0, h:h + 1, r]
     last = jnp.broadcast_to(cum[:, sq - 1:sq], (1, sq))
     x = x_ref[0, h * plan.p:(h + 1) * plan.p, r].astype(jnp.float32)
-    d = d_ref[pl.program_id(2) * plan.hb + h]
+    di = pl.program_id(2) * plan.hb + h
+    if d_group is not None:
+        di = di + (pl.program_id(0) % d_group[0]) * d_group[1]
+    d = d_ref[di]
     return _Head(cum, dt, jnp.exp(cum), jnp.exp(last - cum), jnp.exp(last), x, x * dt, d)
 
 
@@ -141,14 +160,14 @@ def _decay(tri, cols, head: _Head, h: int):
 
 
 def _rows(plan: Plan, heads, width: int):
-    """(G*P, width) of per-head (1, ·) rows, each repeated over its head's
+    """(g*P, width) of per-head (1, ·) rows, each repeated over its head's
     P rows; a row of one value (its first lane) is spread over ``width``."""
     return jnp.concatenate([jnp.broadcast_to(r if r.shape[1] == width else r[:, :1],
                                              (plan.p, width)) for r in heads], axis=0)
 
 
 def _fwd_kernel(x_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, y_ref, final_ref, *rest,
-                plan: Plan, save_states: bool):
+                plan: Plan, save_states: bool, d_group):
     states_ref, cb_ref = rest if save_states else (None, rest[0])
     ci, hi = pl.program_id(1), pl.program_id(2)
     sq, p, n = plan.sq, plan.p, final_ref.shape[-1]
@@ -175,8 +194,8 @@ def _fwd_kernel(x_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, y_ref, final_ref,
         cols = cum_ref[0, :, r].T                                       # (sq, hb)
         for k in range(groups):
             j, heads = hi * groups + k, range(k * plan.g, (k + 1) * plan.g)
-            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r) for h in heads]
-            state = final_ref[0, j]                                     # (G*P, N)
+            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r, d_group) for h in heads]
+            state = final_ref[0, j]                                     # (g*P, N)
             y_off = _dot(state, ct) * _rows(plan, [hd.decay_in for hd in hs], sq)
             for g, (h, hd) in enumerate(zip(heads, hs)):
                 y = (y_off[g * p:(g + 1) * p] + hd.d * hd.x
@@ -189,7 +208,7 @@ def _fwd_kernel(x_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, y_ref, final_ref,
 
 def _bwd_kernel(x_ref, dy_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, states_ref,
                 dfinal_ref, dfinal_s_ref, dx_ref, dcum_ref, ddt_ref, dd_ref, dbt_ref, dct_ref,
-                dstate_ref, dstate_s_ref, cb_ref, dcb_ref, cols_ref, *, plan: Plan):
+                dstate_ref, dstate_s_ref, cb_ref, dcb_ref, cols_ref, *, plan: Plan, d_group):
     ri, hi = pl.program_id(1), pl.program_id(2)
     sq, p, n = plan.sq, plan.p, dstate_ref.shape[-1]
     groups, subs = plan.hb // plan.g, plan.q // plan.sq
@@ -219,11 +238,11 @@ def _bwd_kernel(x_ref, dy_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, states_re
     for k in range(groups):
         j, heads = hi * groups + k, range(k * plan.g, (k + 1) * plan.g)
         # The states at each sub-chunk's start, rows (T) and columns (S) per head.
-        state_t = [states_ref[0, 0, k]]                                 # (G*P, N)
-        state_s = [state_t[0].T]                                        # (N, G*P)
+        state_t = [states_ref[0, 0, k]]                                 # (g*P, N)
+        state_s = [state_t[0].T]                                        # (N, g*P)
         for s in range(subs - 1):
             r = slice(s * sq, (s + 1) * sq)
-            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r) for h in heads]
+            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r, d_group) for h in heads]
             xs_out = jnp.concatenate([hd.xs * hd.decay_out for hd in hs], axis=0)
             bt = chunk(bt_ref, s)
             state_t.append(state_t[-1] * _rows(plan, [hd.decay_all for hd in hs], n)
@@ -234,12 +253,12 @@ def _bwd_kernel(x_ref, dy_ref, cum_ref, dt_ref, bt_ref, ct_ref, d_ref, states_re
         for s in reversed(range(subs)):
             r = slice(s * sq, (s + 1) * sq)
             ct, bt = chunk(ct_ref, s), chunk(bt_ref, s)
-            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r) for h in heads]
+            hs = [_head(plan, x_ref, cum_ref, dt_ref, d_ref, h, r, d_group) for h in heads]
             state, dstate = state_t[s], dstate_ref[j]                   # start; end's cotangent
             dy = dy_ref[0, k * plan.g * p:(k + 1) * plan.g * p, r].astype(jnp.float32)
             dy_in = dy * _rows(plan, [hd.decay_in for hd in hs], sq)
             xs_out = jnp.concatenate([hd.xs * hd.decay_out for hd in hs], axis=0)
-            bd = _dot(dstate, bt)                                       # (G*P, sq)
+            bd = _dot(dstate, bt)                                       # (g*P, sq)
             t_in = dy_in * _dot(state, ct)
             t_out = xs_out * bd
             t_state = state * dstate
@@ -291,8 +310,8 @@ class _Specs(NamedTuple):
     row: pl.BlockSpec       # per head and step, (B, H, S)
     nq: pl.BlockSpec        # B, C and their gradients, (B, N, S)
     d: pl.BlockSpec         # D, (H,), in SMEM
-    states: pl.BlockSpec    # a chunk's start state, (B, n_chunks, H/G, G*P, N)
-    whole: pl.BlockSpec     # a sequence's state, (B, H/G, G*P, N) or (B, H/G, N, G*P)
+    states: pl.BlockSpec    # a chunk's start state, (B, n_chunks, H/g, g*P, N)
+    whole: pl.BlockSpec     # a sequence's state, (B, H/g, g*P, N) or (B, H/g, N, g*P)
 
 
 def _specs(plan: Plan, n: int, ng: int, chunk_of) -> _Specs:
@@ -311,6 +330,12 @@ def _grid(plan: Plan, x_t):
     return b, s // plan.q, hp // plan.p // plan.hb, hp // (plan.g * plan.p)
 
 
+def _d_group(cum, D):
+    """(G, H/G) where the batch axis folds G > 1 groups of B/C, else None."""
+    groups = D.shape[0] // cum.shape[1]
+    return (groups, cum.shape[1]) if groups > 1 else None
+
+
 def _fwd(plan: Plan, x_t, cum, dts, bt, ct, D, save_states: bool):
     b, nc, nh, ng = _grid(plan, x_t)
     n, gw = bt.shape[1], plan.g * plan.p
@@ -322,7 +347,8 @@ def _fwd(plan: Plan, x_t, cum, dts, bt, ct, D, save_states: bool):
         out_specs.append(sp.states)
         out_shape.append(jax.ShapeDtypeStruct((b, nc, ng, gw, n), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, plan=plan, save_states=save_states),
+        functools.partial(_fwd_kernel, plan=plan, save_states=save_states,
+                          d_group=_d_group(cum, D)),
         grid=(b, nc, nh),
         in_specs=[sp.seq, sp.row, sp.row, sp.nq, sp.nq, sp.d],
         out_specs=out_specs,
@@ -342,7 +368,7 @@ def _bwd(plan: Plan, x_t, dy_t, cum, dts, bt, ct, D, states, dfinal):
     whole_s = pl.BlockSpec((1, ng, n, gw), lambda b, c, h: (b, 0, 0, 0))
     f32 = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, plan=plan),
+        functools.partial(_bwd_kernel, plan=plan, d_group=_d_group(cum, D)),
         grid=(b, nc, nh),
         in_specs=[sp.seq, sp.seq, sp.row, sp.row, sp.nq, sp.nq, sp.d, sp.states,
                   sp.whole, whole_s],
@@ -374,6 +400,7 @@ def _scan_bwd(plan, res, cts):
     dy_t, dfinal = cts
     dx_t, dcum, ddt, dd, dbt, dct = _bwd(plan, x_t, dy_t.astype(x_t.dtype), cum, dts, bt, ct,
                                          D, states, dfinal)
+    dd = dd.reshape(-1, D.shape[0], dd.shape[-1])              # groups unfolded
     return (dx_t, dcum, ddt, dbt.astype(bt.dtype), dct.astype(ct.dtype),
             dd.sum(axis=(0, 2)).astype(D.dtype))
 
@@ -386,8 +413,8 @@ def ssd_scan_pallas(
     x: jnp.ndarray,      # (B, S, H, P)
     dtA: jnp.ndarray,    # (B, S, H) log decay per step
     dt: jnp.ndarray,     # (B, S, H) input scale
-    B_: jnp.ndarray,     # (B, S, N)
-    C_: jnp.ndarray,     # (B, S, N)
+    B_: jnp.ndarray,     # (B, S, G, N)
+    C_: jnp.ndarray,     # (B, S, G, N)
     D: jnp.ndarray,      # (H,) skip
     *,
     chunk: int = 256,
@@ -397,14 +424,17 @@ def ssd_scan_pallas(
     (B, H, N, P) f32: ``ref.ssd_chunked`` with no initial state, plus the
     skip; differentiable."""
     b, s, h, p = x.shape
-    n = B_.shape[-1]
-    plan = plan_blocks(x.shape, n, chunk, interpret)
+    g, n = B_.shape[-2:]
+    plan = plan_blocks(x.shape, n, chunk, interpret, groups=g)
     if plan is None:
-        raise ValueError(f"SSD blocks cannot tile x {x.shape} with N {n}, chunk {chunk}")
+        raise ValueError(f"SSD blocks cannot tile x {x.shape} with {g} groups of N {n}, "
+                         f"chunk {chunk}")
     cum = jnp.cumsum(dtA.astype(jnp.float32).reshape(b, s // plan.sq, plan.sq, h), axis=2)
     cum = cum.reshape(b, s, h).transpose(0, 2, 1)                  # (B, H, S)
     dts = dt.astype(jnp.float32).transpose(0, 2, 1)
-    y_t, final = _scan(plan, x.reshape(b, s, h * p).transpose(0, 2, 1), cum, dts,
-                       B_.transpose(0, 2, 1), C_.transpose(0, 2, 1), D.astype(jnp.float32))
+    fold = lambda a: a.reshape(b * g, a.shape[1] // g, s)            # (B*G, ./G, S)
+    seq_minor = lambda bc: bc.transpose(0, 2, 3, 1).reshape(b * g, n, s)
+    y_t, final = _scan(plan, fold(x.reshape(b, s, h * p).transpose(0, 2, 1)), fold(cum),
+                       fold(dts), seq_minor(B_), seq_minor(C_), D.astype(jnp.float32))
     final = final.reshape(b, h, p, n).transpose(0, 1, 3, 2)
-    return y_t.transpose(0, 2, 1).reshape(b, s, h, p), final
+    return y_t.reshape(b, h * p, s).transpose(0, 2, 1).reshape(b, s, h, p), final

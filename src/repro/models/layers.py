@@ -7,11 +7,16 @@ Notable implementation choices (see DESIGN.md §2):
     ``repro.kernels.flash_attention`` and keeps the dry-run memory term
     honest. Sliding-window layers slice only the in-window KV blocks, so
     local attention is genuinely sub-quadratic in HLO FLOPs too.
-  * MoE uses sort/gather dispatch + capacity-padded expert buffers +
-    scatter-add combine. Dispatch/combine are data movement (zero matmul
-    FLOPs); expert compute is exactly ``top_k x capacity_factor`` times the
-    dense-equivalent — the GShard one-hot-einsum formulation would inflate
-    HLO FLOPs by >100x and ruin the roofline accounting.
+  * The expert layer (nemotron_h) holds a share of the routed experts,
+    routes over all of them and drops nothing: the pairs routed to the
+    held experts are sorted into one buffer and run through a grouped
+    matmul that does no work past each expert's rows.
+  * ``moe_apply`` (moonshot, jamba) uses sort/gather dispatch +
+    capacity-padded expert buffers + scatter-add combine. Dispatch/combine
+    are data movement (zero matmul FLOPs); expert compute is exactly
+    ``top_k x capacity_factor`` times the dense-equivalent — the GShard
+    one-hot-einsum formulation would inflate HLO FLOPs by >100x and ruin
+    the roofline accounting.
   * GQA is implemented by repeating KV heads to the Q-head count *in the
     compute path only*; caches store the unrepeated KV.
 """
@@ -23,7 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.kernels import ops
 from repro.models.module import dense_init, dtype_of, ones_init, zeros_init
+from repro.obs import device_scope
 
 # ---------------------------------------------------------------------------
 # Norms
@@ -110,8 +117,9 @@ def _project_qkv(params, x, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -424,6 +432,96 @@ def moe_apply(params, x: jnp.ndarray, cfg: ModelConfig):
     flat_tok = buf_tok.reshape(b, e * cap)
     y = jnp.zeros((b, s, d), jnp.float32)
     y = jax.vmap(lambda yy, tt, cc: yy.at[tt].add(cc))(y, flat_tok, flat_contrib)
+    return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Expert layer (nemotron_h) — a held share of the routed experts, dropless
+# ---------------------------------------------------------------------------
+
+
+def experts_init(key, cfg: ModelConfig) -> dict:
+    """The router over all ``n_experts`` routed experts, the bias that
+    shifts their selection, the ``n_held`` relu^2 experts this chip
+    holds (ids [0, n_held)) and the shared expert. Weights are laid out
+    fan-in first, as ``dense_init`` lays out every weight: ``w_up``
+    (D, E_held, F), ``w_down`` (F, E_held, D)."""
+    dt = dtype_of(cfg.param_dtype)
+    d, f, fs, eh = cfg.d_model, cfg.d_ff, cfg.shared_expert_ff, cfg.n_held
+    kr, k1, k2, k3, k4 = jax.random.split(key, 5)
+    return {
+        "router": dense_init(kr, d, (cfg.n_experts,), jnp.float32),
+        "e_score_correction_bias": zeros_init((cfg.n_experts,), jnp.float32),
+        "w_up": dense_init(k1, d, (eh, f), dt),
+        "w_down": dense_init(k2, f, (eh, d), dt),
+        "shared": {"w_up": dense_init(k3, d, (fs,), dt),
+                   "w_down": dense_init(k4, fs, (d,), dt)},
+    }
+
+
+def route(params, x32, cfg: ModelConfig):
+    """Top-k routed experts of each token and their gate weights, over
+    all ``n_experts``: the sigmoid router in float32 chooses on the
+    scores plus ``e_score_correction_bias`` (which only selects: it
+    carries no gradient); the gate weights are the chosen scores,
+    normalized over the k, times ``routed_scaling``.
+    x32: (T, D) -> ids (T, k) int32, weights (T, k)."""
+    logits = jnp.dot(x32, params["router"], precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + jax.lax.stop_gradient(params["e_score_correction_bias"])
+    _, ids = jax.lax.top_k(choice, cfg.top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids, w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+
+
+def relu2_mlp(params, x):
+    """down(relu(x up)^2), no gate: one relu^2 expert."""
+    u = jnp.einsum("...d,df->...f", x, params["w_up"])
+    return jnp.einsum("...f,fd->...d", jnp.square(jax.nn.relu(u)), params["w_down"])
+
+
+def routed_experts(params, x: jnp.ndarray, cfg: ModelConfig, first: int = 0):
+    """The routed experts' part of the layer, for the experts held in
+    ``params`` (ids ``first`` onward). x: (B, S, D) -> float32 (B, S, D).
+
+    The (token, slot) pairs routed to held experts are sorted by expert
+    into a buffer of B*S*min(k, E_held) rows, the most they can fill, so
+    nothing is dropped; the grouped matmuls do no work past each
+    expert's rows, and a weighted float32 scatter-add combines. Pairs
+    routed to experts held elsewhere contribute nothing."""
+    b, s, d = x.shape
+    k, held = cfg.top_k, params["w_up"].shape[1]
+    t, m = b * s, b * s * min(cfg.top_k, held)
+    xt = x.reshape(t, d)
+    with device_scope("moe.route"):
+        ids, gate = route(params, xt.astype(jnp.float32), cfg)
+        local = ids.reshape(t * k) - first
+        local = jnp.where((local >= 0) & (local < held), local, held)   # not held: last
+        order = jnp.argsort(local, stable=True)[:m]
+        sizes = jax.ops.segment_sum(jnp.ones_like(local), local, held)  # drops "not held"
+        valid = (jnp.arange(m) < sizes.sum())[:, None]
+        tok = order // k
+        xb = jnp.where(valid, xt[tok], 0)
+    with device_scope("moe.experts"):
+        up = ops.grouped_matmul(xb, jnp.moveaxis(params["w_up"], 1, 0), sizes)
+        yb = ops.grouped_matmul(jnp.square(jax.nn.relu(up)),
+                                jnp.moveaxis(params["w_down"], 1, 0), sizes)
+    with device_scope("moe.route"):
+        # Rows past every group are undefined (the kernel leaves them
+        # unwritten): mask them before they meet the gate weights, whose
+        # gradient would otherwise read them.
+        w = gate.reshape(t * k)[order][:, None]
+        contrib = jnp.where(valid, yb, 0).astype(jnp.float32) * w
+        y = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib)
+    return y.reshape(b, s, d)
+
+
+def expert_layer_apply(params, x: jnp.ndarray, cfg: ModelConfig):
+    """The expert layer: the held routed experts plus the shared expert.
+    x: (B, S, D) -> (B, S, D)."""
+    y = routed_experts(params, x, cfg)
+    with device_scope("moe.shared"):
+        y = y + relu2_mlp(params["shared"], x).astype(jnp.float32)
     return y.astype(x.dtype)
 
 

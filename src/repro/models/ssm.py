@@ -7,10 +7,12 @@ All decay products are computed in log space (A < 0 so products <= 1).
 TP layout (DESIGN.md §4/§5): projections are split per component with the
 head dimension exposed — ``w_z/w_x: (D, H, P)``, ``w_dt: (D, H)``,
 ``w_out: (H, P, D)`` — so heads shard cleanly over the ``model`` mesh axis
-(SSD is per-head; B/C are head-shared and replicated; the only cross-shard
-reduction is the out-projection's standard TP all-reduce). The gated norm
-is per-head RMS (mamba2's grouped RMSNorm), which keeps normalization
-shard-local.
+(SSD is per-head; B/C are replicated; the only cross-shard reduction is
+the out-projection's standard TP all-reduce). B and C come in
+``ssm_groups`` groups of ``ssm_state`` lanes, flattened to ``(D, G*N)``
+(at one group mamba2's ``(D, N)``); head h reads group h // (H / G). The
+gated RMSNorm runs over groups of ``ssm_norm_lanes`` lanes: one head for
+mamba2 (shard-local), d_inner / G lanes for Nemotron-H.
 
 The chunked scan itself runs through ``kernels.ops.ssd_scan``: the Pallas
 kernel pair of ``repro.kernels.ssd_scan`` on TPU, its XLA twin
@@ -31,14 +33,15 @@ from repro.models.module import dense_init, dtype_of, zeros_init
 
 class MambaCache(NamedTuple):
     conv_x: jnp.ndarray  # (B, W-1, H, P)
-    conv_B: jnp.ndarray  # (B, W-1, N)
-    conv_C: jnp.ndarray  # (B, W-1, N)
+    conv_B: jnp.ndarray  # (B, W-1, G*N)
+    conv_C: jnp.ndarray  # (B, W-1, G*N)
     ssm: jnp.ndarray     # (B, H, N, P) — recurrent state (f32)
 
 
 def ssm_init(key, cfg: ModelConfig) -> dict:
     dt = dtype_of(cfg.param_dtype)
-    d, n, h, p = cfg.d_model, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_headdim
+    d, h, p = cfg.d_model, cfg.ssm_nheads, cfg.ssm_headdim
+    n = cfg.ssm_groups * cfg.ssm_state
     ks = jax.random.split(key, 8)
     return {
         "w_z": dense_init(ks[0], d, (h, p), dt),
@@ -60,15 +63,23 @@ def ssm_init(key, cfg: ModelConfig) -> dict:
     }
 
 
-def _head_rmsnorm(scale, y, eps: float):
-    """Per-head RMS over P (mamba2 grouped RMSNorm). y: (..., H, P)."""
+def _group_rmsnorm(scale, y, lanes: int, eps: float):
+    """RMSNorm over groups of ``lanes`` consecutive lanes of the heads
+    (mamba2's grouped RMSNorm). y: (..., H, P); scale: (H, P)."""
     y32 = y.astype(jnp.float32)
-    var = jnp.mean(jnp.square(y32), axis=-1, keepdims=True)
-    return (y32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(y.dtype)
+    g = y32.reshape(*y.shape[:-2], -1, lanes)
+    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    g = (g * jax.lax.rsqrt(var + eps)).reshape(y.shape)
+    return (g * scale.astype(jnp.float32)).astype(y.dtype)
+
+
+def bc_groups(bc, cfg: ModelConfig):
+    """B or C, (..., G*N), as (..., G, N)."""
+    return bc.reshape(*bc.shape[:-1], cfg.ssm_groups, cfg.ssm_state)
 
 
 def _project(params, u, cfg: ModelConfig):
-    """u: (B,S,D) -> z,x: (B,S,H,P); B,C: (B,S,N); dt: (B,S,H) (pre-conv)."""
+    """u: (B,S,D) -> z,x: (B,S,H,P); B,C: (B,S,G*N); dt: (B,S,H) (pre-conv)."""
     z = jnp.einsum("bsd,dhp->bshp", u, params["w_z"])
     x = jnp.einsum("bsd,dhp->bshp", u, params["w_x"])
     B_ = jnp.einsum("bsd,dn->bsn", u, params["w_B"])
@@ -100,9 +111,10 @@ def _ssd_core(params, u, cfg: ModelConfig, init_state=None):
     x, B_, C_ = _conv_all(params, x, B_, C_, cfg)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
-    y, state = ops.ssd_scan(x, dt * A, dt, B_, C_, params["D"], init_state,
-                            chunk=cfg.ssm_chunk)
-    y = _head_rmsnorm(params["norm_scale"], y.astype(u.dtype) * jax.nn.silu(z), cfg.norm_eps)
+    y, state = ops.ssd_scan(x, dt * A, dt, bc_groups(B_, cfg), bc_groups(C_, cfg),
+                            params["D"], init_state, chunk=cfg.ssm_chunk)
+    y = _group_rmsnorm(params["norm_scale"], y.astype(u.dtype) * jax.nn.silu(z),
+                       cfg.ssm_norm_lanes, cfg.norm_eps)
     out = jnp.einsum("bshp,hpd->bsd", y, params["w_out"])
     return out, state, raw_x_tail
 
@@ -127,10 +139,11 @@ def ssm_prefill(params, u, cfg: ModelConfig):
 
 def ssm_init_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> MambaCache:
     n, h, p, w = cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_headdim, cfg.conv_width
+    gn = cfg.ssm_groups * n
     return MambaCache(
         conv_x=jnp.zeros((batch, w - 1, h, p), dtype),
-        conv_B=jnp.zeros((batch, w - 1, n), dtype),
-        conv_C=jnp.zeros((batch, w - 1, n), dtype),
+        conv_B=jnp.zeros((batch, w - 1, gn), dtype),
+        conv_C=jnp.zeros((batch, w - 1, gn), dtype),
         ssm=jnp.zeros((batch, h, n, p), jnp.float32),
     )
 
@@ -138,7 +151,7 @@ def ssm_init_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> MambaCac
 def ssm_decode(params, u, cache: MambaCache, cfg: ModelConfig):
     """Single-token recurrent step. u: (B, 1, D)."""
     b = u.shape[0]
-    n, h, p, w = cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_headdim, cfg.conv_width
+    g, h, p = cfg.ssm_groups, cfg.ssm_nheads, cfg.ssm_headdim
     z, x_new, B_new, C_new, dt = _project(params, u, cfg)
 
     def roll(state, new, wgt, bias):
@@ -157,14 +170,16 @@ def ssm_decode(params, u, cache: MambaCache, cfg: ModelConfig):
     A = -jnp.exp(params["A_log"])
     a = jnp.exp(dt * A)                                                     # (B,H)
 
-    dx = x * dt[..., None]                                                  # (B,H,P)
+    dx = (x * dt[..., None]).reshape(b, g, h // g, p)                      # (B,G,H/G,P)
     new_state = cache.ssm * a[:, :, None, None] + jnp.einsum(
-        "bn,bhp->bhnp", B_, dx
-    )
-    y = jnp.einsum("bn,bhnp->bhp", C_, new_state)
+        "bgn,bghp->bghnp", bc_groups(B_, cfg), dx
+    ).reshape(cache.ssm.shape)
+    y = jnp.einsum("bgn,bghnp->bghp", bc_groups(C_, cfg),
+                   new_state.reshape(b, g, h // g, *new_state.shape[2:])).reshape(b, h, p)
     y = y + params["D"][None, :, None] * x
     y = y[:, None].astype(u.dtype)                                          # (B,1,H,P)
-    y = _head_rmsnorm(params["norm_scale"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = _group_rmsnorm(params["norm_scale"], y * jax.nn.silu(z), cfg.ssm_norm_lanes,
+                       cfg.norm_eps)
     out = jnp.einsum("bshp,hpd->bsd", y, params["w_out"])
     new_cache = MambaCache(
         conv_x=new_cx.astype(cache.conv_x.dtype),

@@ -5,7 +5,8 @@ Structure (DESIGN.md §3):
     boundaries are the Hapi split candidates ("for DNNs structured as a
     sequence of blocks we split at block boundary", paper Table 1).
     dense/moe/ssm: block == one layer; gemma2: block == (local, global)
-    pair; jamba: block == one 8-sublayer period.
+    pair; jamba: block == one 8-sublayer period; nemotron_h: block == one
+    period of ``layer_pattern``, one single-branch layer per letter.
   * ``forward_prefix`` / ``forward_suffix`` execute blocks [0, split) and
     [split, N) — the two halves of the paper's tier split. The split is
     static (chosen once per application), so the stacked params are sliced
@@ -33,11 +34,22 @@ from repro.models.module import dtype_of, embed_init, maybe_remat, slice_stack, 
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class SubLayer:
-    mixer: str                 # "attn" | "attn_local" | "mamba"
-    ffn: str                   # "mlp" | "moe" | "none"
+    mixer: str                 # "attn" | "attn_local" | "mamba" | "none"
+    ffn: str                   # "mlp" | "moe" | "experts" | "none"
+
+
+# The letters of ``ModelConfig.layer_pattern``: each layer is one pre-norm
+# residual branch with one mixer (M, *) or one expert layer (E).
+PATTERN_LAYERS = {
+    "M": SubLayer("mamba", "none"),
+    "*": SubLayer("attn", "none"),
+    "E": SubLayer("none", "experts"),
+}
 
 
 def block_plan(cfg: ModelConfig) -> List[SubLayer]:
+    if cfg.layer_pattern:
+        return [PATTERN_LAYERS[c] for c in cfg.layer_pattern]
     if cfg.family in ("dense", "vlm"):
         if cfg.local_global_period:
             # gemma2: alternate sliding-window local and global attention.
@@ -67,7 +79,7 @@ def _sublayer_init(key, cfg: ModelConfig, sub: SubLayer) -> dict:
     if sub.mixer in ("attn", "attn_local"):
         p["ln_mixer"] = L.rmsnorm_init(cfg.d_model, dt)
         p["attn"] = L.attention_init(keys[0], cfg)
-    else:
+    elif sub.mixer == "mamba":
         p["ln_mixer"] = L.rmsnorm_init(cfg.d_model, dt)
         p["mamba"] = S.ssm_init(keys[0], cfg)
     if sub.ffn == "mlp":
@@ -76,7 +88,22 @@ def _sublayer_init(key, cfg: ModelConfig, sub: SubLayer) -> dict:
     elif sub.ffn == "moe":
         p["ln_ffn"] = L.rmsnorm_init(cfg.d_model, dt)
         p["moe"] = L.moe_init(keys[1], cfg)
+    elif sub.ffn == "experts":
+        p["ln_ffn"] = L.rmsnorm_init(cfg.d_model, dt)
+        p["experts"] = L.experts_init(keys[1], cfg)
     return p
+
+
+def _ffn_apply(p, h, cfg: ModelConfig, sub: SubLayer):
+    """The sublayer's second residual branch, if it has one."""
+    if sub.ffn == "none":
+        return h
+    x = L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps)
+    if sub.ffn == "mlp":
+        return h + L.mlp_apply(p["mlp"], x)
+    if sub.ffn == "moe":
+        return h + L.moe_apply(p["moe"], x, cfg)
+    return h + L.expert_layer_apply(p["experts"], x, cfg)
 
 
 def _sublayer_apply(p, h, cfg: ModelConfig, sub: SubLayer, positions):
@@ -90,31 +117,25 @@ def _sublayer_apply(p, h, cfg: ModelConfig, sub: SubLayer, positions):
             p["attn"], L.rmsnorm(p["ln_mixer"], h, cfg.norm_eps), cfg,
             window=cfg.sliding_window, positions=positions,
         )
-    else:
+    elif sub.mixer == "mamba":
         h = h + S.ssm_apply(p["mamba"], L.rmsnorm(p["ln_mixer"], h, cfg.norm_eps), cfg)
-    if sub.ffn == "mlp":
-        h = h + L.mlp_apply(p["mlp"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps))
-    elif sub.ffn == "moe":
-        h = h + L.moe_apply(p["moe"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps), cfg)
-    return h
+    return _ffn_apply(p, h, cfg, sub)
 
 
 def _sublayer_prefill(p, h, cfg: ModelConfig, sub: SubLayer, positions):
-    """Like apply, but also returns the decode cache for this sublayer."""
+    """Like apply, but also returns the decode cache for this sublayer
+    (None for a layer without a mixer)."""
+    cache = None
     if sub.mixer in ("attn", "attn_local"):
         x = L.rmsnorm(p["ln_mixer"], h, cfg.norm_eps)
         win = cfg.sliding_window if sub.mixer == "attn_local" else None
         y, cache = _attention_prefill(p["attn"], x, cfg, window=win, positions=positions)
         h = h + y
-    else:
+    elif sub.mixer == "mamba":
         x = L.rmsnorm(p["ln_mixer"], h, cfg.norm_eps)
         y, cache = S.ssm_prefill(p["mamba"], x, cfg)
         h = h + y
-    if sub.ffn == "mlp":
-        h = h + L.mlp_apply(p["mlp"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps))
-    elif sub.ffn == "moe":
-        h = h + L.moe_apply(p["moe"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps), cfg)
-    return h, cache
+    return _ffn_apply(p, h, cfg, sub), cache
 
 
 def _sublayer_decode(p, h, cache, pos, cfg: ModelConfig, sub: SubLayer):
@@ -123,15 +144,11 @@ def _sublayer_decode(p, h, cache, pos, cfg: ModelConfig, sub: SubLayer):
         win = cfg.sliding_window if sub.mixer == "attn_local" else None
         y, cache = L.attention_decode(p["attn"], x, cache, pos, cfg, window=win)
         h = h + y
-    else:
+    elif sub.mixer == "mamba":
         x = L.rmsnorm(p["ln_mixer"], h, cfg.norm_eps)
         y, cache = S.ssm_decode(p["mamba"], x, cache, cfg)
         h = h + y
-    if sub.ffn == "mlp":
-        h = h + L.mlp_apply(p["mlp"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps))
-    elif sub.ffn == "moe":
-        h = h + L.moe_apply(p["moe"], L.rmsnorm(p["ln_ffn"], h, cfg.norm_eps), cfg)
-    return h, cache
+    return _ffn_apply(p, h, cfg, sub), cache
 
 
 def _attention_prefill(params, x, cfg: ModelConfig, *, window, positions):
@@ -146,7 +163,8 @@ def _attention_prefill(params, x, cfg: ModelConfig, *, window, positions):
         v = v + params["bv"]
     if cfg.qk_norm:
         k = L.rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        k = L.rope(k, positions, cfg.rope_theta)
     return y, L.KVCache(k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
 
 
@@ -187,8 +205,10 @@ def block_init_cache(cfg: ModelConfig, batch: int, smax: int) -> dict:
                 k=jnp.zeros((batch, smax, cfg.n_kv_heads, cfg.hdim), jnp.bfloat16),
                 v=jnp.zeros((batch, smax, cfg.n_kv_heads, cfg.hdim), jnp.bfloat16),
             )
-        else:
+        elif sub.mixer == "mamba":
             out[f"sub{i}"] = S.ssm_init_cache(cfg, batch)
+        else:
+            out[f"sub{i}"] = None
     return out
 
 
@@ -243,7 +263,7 @@ def cross_entropy(logits, labels, mask=None):
 
 
 def build_lm(cfg: ModelConfig) -> Model:
-    """Decoder LM for families dense/moe/ssm/hybrid/vlm."""
+    """Decoder LM for families dense/moe/ssm/hybrid/vlm and layer patterns."""
     remat_name = "block"
 
     def init(key):

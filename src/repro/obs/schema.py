@@ -19,6 +19,11 @@ metrics registry may emit, mirroring how
   the three sets above these are not virtual time: each is a
   ``jax.named_scope``, compile-time metadata (``op_name``) that a
   profiler trace of the compiled program carries on every op.
+* :data:`LAYER_SCOPES` — device scopes of single layers inside those
+  phases (the expert layer's routing, grouped matmuls and shared
+  expert). They carry no ``hapi.`` prefix, so a phase reader, which
+  keeps the innermost ``hapi.*`` component, still counts their ops in
+  the phase around them.
 
 The tracer and the registry validate against these sets at emission
 time, so an unregistered name fails the emitting run loudly instead of
@@ -74,16 +79,24 @@ DEVICE_SCOPES = frozenset({
 })
 
 
+#: Named scopes of single layers, nested inside the phases above.
+LAYER_SCOPES = frozenset({
+    "moe.route",         # expert layer: router, dispatch to the held experts, combine
+    "moe.experts",       # expert layer: grouped matmuls of the held experts
+    "moe.shared",        # expert layer: the shared expert
+})
+
+
 def device_scope(name: str):
-    """``jax.named_scope(name)`` for a registered phase of the program.
+    """``jax.named_scope(name)`` for a registered phase or layer scope.
 
     The scope costs nothing at run time: it only names the ops traced
     inside it, in their ``op_name`` metadata (the backward pass keeps it
     as ``transpose(jvp(<name>))``)."""
-    if name not in DEVICE_SCOPES:
+    if name not in DEVICE_SCOPES | LAYER_SCOPES:
         raise ValueError(
-            f"device scope {name!r} is not in repro.obs.schema.DEVICE_SCOPES; "
-            f"register it there so trace readers can find it")
+            f"device scope {name!r} is not in repro.obs.schema.DEVICE_SCOPES or "
+            f"LAYER_SCOPES; register it there so trace readers can find it")
     import jax
 
     return jax.named_scope(name)

@@ -80,16 +80,16 @@ def test_decode_attention(b, s, hq, hkv, hd, length, dtype):
     )
 
 
-def _ssd_inputs(b, s, h, p, n, strong=False, dtype=jnp.float32):
-    """SSD inputs as the mixer makes them, (x, dtA, dt, B, C, D); ``strong``
-    gives decays whose exponents above the diagonal overflow f32 unless
-    masked."""
+def _ssd_inputs(b, s, h, p, n, strong=False, dtype=jnp.float32, g=1):
+    """SSD inputs as the mixer makes them, (x, dtA, dt, B, C, D), with B
+    and C in ``g`` groups; ``strong`` gives decays whose exponents above
+    the diagonal overflow f32 unless masked."""
     ks = jax.random.split(KEY, 6)
     x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)) + (3.0 if strong else 0.0))
     A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3 + (1.5 if strong else 0.0))
-    B_ = (jax.random.normal(ks[3], (b, s, n)) * 0.3).astype(dtype)
-    C_ = (jax.random.normal(ks[4], (b, s, n)) * 0.3).astype(dtype)
+    B_ = (jax.random.normal(ks[3], (b, s, g, n)) * 0.3).astype(dtype)
+    C_ = (jax.random.normal(ks[4], (b, s, g, n)) * 0.3).astype(dtype)
     D = jax.random.normal(ks[5], (h,))
     return x, dt * A, dt, B_, C_, D
 
@@ -102,21 +102,55 @@ def _with_skip(scan, args):
     return (y + D[None, None, :, None] * x.astype(jnp.float32)).astype(x.dtype), state
 
 
+def _ssd_quadratic(x, dtA, dt, B_, C_, D):
+    """The SSD in its quadratic (dual) form, per group, plus the skip:
+    y_t = sum_{s <= t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s + D x_t, where
+    head h reads group h // (H / G); float32, no chunks, cast to x's dtype
+    as the mixer casts it."""
+    b, s, h, p = x.shape
+    g = B_.shape[2]
+    x32 = x.astype(jnp.float32)
+    cum = jnp.cumsum(dtA.astype(jnp.float32), axis=1)                   # (B, S, H)
+    tri = jnp.tril(jnp.ones((s, s), bool))[None, :, :, None]
+    seg = jnp.exp(jnp.where(tri, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))
+    cb = jnp.einsum("btgn,bsgn->btsg", C_.astype(jnp.float32), B_.astype(jnp.float32),
+                    precision="highest")
+    w = seg * jnp.repeat(cb, h // g, axis=-1)                           # (B, T, S, H)
+    y = jnp.einsum("btsh,bshp->bthp", w, x32 * dt[..., None], precision="highest")
+    return (y + D[None, None, :, None] * x32).astype(x.dtype)
+
+
+def _cases(rows, groups=(1, 2)):
+    """Each row at every group count that divides its heads; one group
+    keeps the row's plain id."""
+    out = []
+    for row in rows:
+        for g in groups:
+            if row[2] % g == 0:
+                rid = "-".join(str(v) for v in row)
+                out.append(pytest.param(*row, g, id=rid if g == 1 else f"{rid}-g{g}"))
+    return out
+
+
 @pytest.mark.parametrize(
-    "b,s,h,p,n,chunk",
-    [
+    "b,s,h,p,n,chunk,g",
+    _cases([
         (2, 512, 8, 64, 128, 128),
         (1, 256, 4, 32, 64, 64),
         (1, 256, 4, 32, 16, 128),   # jamba-like small state
         (2, 128, 8, 64, 128, 128),  # single chunk
-    ],
+    ]),
 )
-def test_ssd_scan(b, s, h, p, n, chunk):
-    args = _ssd_inputs(b, s, h, p, n)
+def test_ssd_scan(b, s, h, p, n, chunk, g):
+    """The kernel against the sequential oracle and the XLA twin, and
+    both against the quadratic form, with B and C in g groups."""
+    args = _ssd_inputs(b, s, h, p, n, g=g)
     y, st = ssd_scan_pallas(*args, chunk=chunk, interpret=True)
     ye, ste = _with_skip(ref.ssd_reference, args)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=2e-3, rtol=2e-3)
-    np.testing.assert_allclose(np.asarray(st), np.asarray(ste), atol=2e-3, rtol=2e-3)
+    yx, stx = _with_skip(lambda *a: ref.ssd_chunked(*a, None, chunk=chunk), args)
+    yq = _ssd_quadratic(*args)
+    for got, want in ((y, ye), (st, ste), (yx, ye), (stx, ste), (y, yq), (yx, yq)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3, rtol=2e-3)
 
 
 def test_ssd_kernel_matches_chunked_model_path():
@@ -130,43 +164,51 @@ def test_ssd_kernel_matches_chunked_model_path():
 
 
 @pytest.mark.parametrize(
-    "b,s,h,p,n,chunk,strong",
-    [
+    "b,s,h,p,n,chunk,strong,g",
+    _cases([
         (2, 256, 4, 32, 16, 64, False),     # four chunks, one head block
         (1, 256, 4, 64, 32, 128, True),     # strong decay (masked exponent)
         (1, 512, 16, 64, 16, 256, False),   # two head blocks, two sub-chunks a chunk
         (2, 512, 8, 128, 16, 256, True),    # P of 128 lanes: one head a group
         (1, 128, 2, 32, 16, 128, False),    # one chunk, H x P under 128 lanes
-    ],
+    ]),
 )
-def test_ssd_scan_grad(b, s, h, p, n, chunk, strong):
+def test_ssd_scan_grad(b, s, h, p, n, chunk, strong, g):
     """The custom VJP's backward kernel against autodiff of the XLA scan,
     with cotangents on y and on the final state, for bf16 x, B and C as
-    the mixer feeds them; the forward also against the sequential oracle."""
-    args = _ssd_inputs(b, s, h, p, n, strong, jnp.bfloat16)
+    the mixer feeds them, with B and C in g groups; the forward also
+    against the sequential oracle, and both scans' VJP of y against the
+    quadratic form's."""
+    args = _ssd_inputs(b, s, h, p, n, strong, jnp.bfloat16, g)
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
     wy = jax.random.normal(ks[0], (b, s, h, p))
     ws = jax.random.normal(ks[1], (b, h, n, p))
 
-    def loss(scan):
+    def loss(scan, with_state=True):
         def f(*a):
             y, st = scan(*a)
-            return jnp.sum(y * wy) + jnp.sum(st * ws)
+            return jnp.sum(y * wy) + (jnp.sum(st * ws) if with_state else 0.0)
         return f
 
     kernel = lambda *a: ssd_scan_pallas(*a, chunk=chunk, interpret=True)
     xla = lambda *a: _with_skip(lambda *u: ref.ssd_chunked(*u, None, chunk), a)
+    quadratic = lambda *a: (_ssd_quadratic(*a), None)
     ye, _ = _with_skip(ref.ssd_reference, args)
     np.testing.assert_allclose(np.asarray(kernel(*args)[0], np.float32),
                                np.asarray(ye, np.float32), atol=3e-2, rtol=1e-2)
-    got = jax.grad(loss(kernel), argnums=range(6))(*args)
-    exp = jax.grad(loss(xla), argnums=range(6))(*args)
-    for name, g, e in zip(("x", "dtA", "dt", "B", "C", "D"), got, exp):
-        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
-        assert np.isfinite(g).all(), name
-        # x, B and C take bf16 cotangents: within a bf16 rounding of the largest
-        tol = 1e-2 if name in ("x", "B", "C") else 1e-4
-        np.testing.assert_allclose(g, e, atol=tol * np.abs(e).max(), err_msg=name)
+    grad = lambda f: jax.grad(f, argnums=range(6))(*args)
+    got, exp = grad(loss(kernel)), grad(loss(xla))
+    pairs = [(got, exp)]
+    if s <= 256:
+        q = grad(loss(quadratic, with_state=False))
+        pairs += [(grad(loss(kernel, False)), q), (grad(loss(xla, False)), q)]
+    for got, exp in pairs:
+        for name, gr, e in zip(("x", "dtA", "dt", "B", "C", "D"), got, exp):
+            gr, e = np.asarray(gr, np.float32), np.asarray(e, np.float32)
+            assert np.isfinite(gr).all(), name
+            # x, B and C take bf16 cotangents: within a bf16 rounding of the largest
+            tol = 1e-2 if name in ("x", "B", "C") else 1e-4
+            np.testing.assert_allclose(gr, e, atol=tol * np.abs(e).max(), err_msg=name)
 
 
 @pytest.mark.parametrize("s,chunk,p,init,kernel", [
@@ -202,6 +244,8 @@ def test_ssd_plan_needs_whole_chunks():
     assert plan_blocks((1, 384, 64, 64), 128, 256) is None
     assert plan_blocks((1, 384, 64, 64), 128, 256, interpret=True) is None
     assert plan_blocks((8, 2048, 64, 64), 128, 256) == (256, 128, 16, 2, 64, False)
+    # Nemotron-H: 8 groups of 8 heads, each group one block of 8 heads.
+    assert plan_blocks((4, 2048, 64, 64), 128, 128, groups=8) == (128, 128, 8, 2, 64, False)
 
 
 @pytest.mark.parametrize("shape", [(4, 100, 256), (3, 384), (2, 7, 512), (1, 128)])

@@ -178,8 +178,8 @@ def test_ssd_gradient_finite_under_strong_decay():
     b, s, h, p, n = 1, 128, 2, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(ks[0], (b, s, h, p))
-    B_ = jax.random.normal(ks[1], (b, s, n))
-    C_ = jax.random.normal(ks[2], (b, s, n))
+    B_ = jax.random.normal(ks[1], (b, s, 1, n))
+    C_ = jax.random.normal(ks[2], (b, s, 1, n))
     dt = jnp.ones((b, s, h))
 
     def f(dtA):

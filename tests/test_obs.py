@@ -32,6 +32,7 @@ import pytest
 from repro.api import HapiCluster, TenantSpec
 from repro.obs import (
     DEVICE_SCOPES,
+    LAYER_SCOPES,
     METRIC_KEYS,
     SPAN_NAMES,
     MetricsRegistry,
@@ -111,14 +112,14 @@ def test_schema_has_no_phantom_metric_keys():
 def test_every_device_scope_site_is_in_schema():
     used = _grep_src(SCOPE_PAT)
     assert used, "grep found no device_scope sites at all"
-    missing = used - DEVICE_SCOPES
+    missing = used - DEVICE_SCOPES - LAYER_SCOPES
     assert not missing, (
         f"device_scope names not registered in "
-        f"repro.obs.schema.DEVICE_SCOPES: {sorted(missing)}")
+        f"repro.obs.schema.DEVICE_SCOPES or LAYER_SCOPES: {sorted(missing)}")
 
 
 def test_schema_has_no_phantom_device_scopes():
-    phantom = DEVICE_SCOPES - _grep_src(SCOPE_PAT)
+    phantom = (DEVICE_SCOPES | LAYER_SCOPES) - _grep_src(SCOPE_PAT)
     assert not phantom, (
         f"schema device scopes no longer used anywhere: {sorted(phantom)}")
 

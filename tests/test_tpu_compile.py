@@ -73,20 +73,23 @@ def test_decode_attention_compiles_mistral_nemo(one_chip):
         _spec(one_chip, (), jnp.int32))
 
 
-@pytest.mark.parametrize("h,n", [(64, 128), (128, 16)])
-def test_ssd_scan_compiles_mamba2(one_chip, h, n):
+@pytest.mark.parametrize("h,n,g,chunk", [pytest.param(64, 128, 1, 256, id="64-128"),
+                                         pytest.param(128, 16, 1, 256, id="128-16"),
+                                         pytest.param(64, 128, 8, 128, id="64-128-g8")])
+def test_ssd_scan_compiles_mamba2(one_chip, h, n, g, chunk):
     """The SSD pair, forward and backward, over a 2048-token sequence of
-    batch 8, P 64, chunk 256: at mamba2's H 64, N 128 and at jamba's H·P
-    8192, N 16."""
+    batch 8, P 64: at mamba2's H 64, N 128 and at jamba's H·P 8192, N 16
+    (chunk 256), and at Nemotron-H's H 64, N 128 in 8 B/C groups (chunk
+    128)."""
     f32 = lambda shape: _spec(one_chip, shape, jnp.float32)
     args = (_spec(one_chip, (8, 2048, h, 64)), f32((8, 2048, h)), f32((8, 2048, h)),
-            _spec(one_chip, (8, 2048, n)), _spec(one_chip, (8, 2048, n)), f32((h,)))
+            _spec(one_chip, (8, 2048, g, n)), _spec(one_chip, (8, 2048, g, n)), f32((h,)))
 
     def loss(*a):
-        y, state = ssd_scan_pallas(*a)
+        y, state = ssd_scan_pallas(*a, chunk=chunk)
         return jnp.sum(y) + jnp.sum(state)
 
-    assert "tpu_custom_call" in _hlo(lambda *a: ssd_scan_pallas(*a), *args)
+    assert "tpu_custom_call" in _hlo(lambda *a: ssd_scan_pallas(*a, chunk=chunk), *args)
     assert _hlo(jax.grad(loss, argnums=range(6)), *args).count("tpu_custom_call") == 2
 
 
@@ -147,3 +150,23 @@ def test_int8_train_step_holds_the_kernels(one_chip, monkeypatch):
     hlo = _hlo(build_hapi_train_step(model, rc, plan), state,
                {"tokens": tokens, "labels": tokens})
     assert hlo.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_matmul_compiles_nemotron(one_chip, monkeypatch, k, n):
+    """The expert layer's grouped matmul, forward and backward, at
+    Nemotron 3 Nano's up (2688 -> 1856) and down (1856 -> 2688) widths
+    for 8 held experts over the 24576-row buffer of a 2 x 2048 batch:
+    the Pallas kernels (gmm forward, gmm and tgmm backward) and no XLA
+    ragged dot, whose TPU rewrite drops the named scopes."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    args = (_spec(one_chip, (24576, k)), _spec(one_chip, (8, k, n)),
+            _spec(one_chip, (8,), jnp.int32))
+    loss = lambda x, w, g: jnp.sum(ops.grouped_matmul(x, w, g).astype(jnp.float32))
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1)), *args)
+    kernels = re.findall(r"%(\w+)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert kernels and all("gmm" in name for name in kernels)
+    assert any("tgmm" in name for name in kernels)
+    assert "ragged-dot" not in hlo
