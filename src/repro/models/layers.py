@@ -480,6 +480,85 @@ def relu2_mlp(params, x):
     return jnp.einsum("...f,fd->...d", jnp.square(jax.nn.relu(u)), params["w_down"])
 
 
+def sort_pairs(ids, held: int, first: int = 0):
+    """The (token, slot) pairs of ``ids`` (T, k) sorted by held expert
+    (ids ``first`` onward, ``held`` of them): ``order`` (T*k,), the pair
+    in each sorted position (pair t*k + j is token t's slot j); ``pos``
+    (T, k), each pair's position, the inverse of ``order`` (a second
+    argsort, of integers); ``sizes`` (held,), each expert's
+    pairs. Pairs routed to experts held elsewhere sort after every
+    group, at positions >= ``sizes.sum()``."""
+    local = ids.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)      # not held: last
+    order = jnp.argsort(local, stable=True)
+    pos = jnp.argsort(order)
+    sizes = jax.ops.segment_sum(jnp.ones_like(local), local, held)    # drops "not held"
+    return order, pos.reshape(ids.shape), sizes
+
+
+def _slot_rows(rows, pos, n):
+    """For each slot j: whether each token's pair has a row (pos < n),
+    (T, 1), and the row ``rows[pos[:, j]]`` in float32, (T, D); one row
+    gather a slot. A pair without a row reads row 0, to be selected away
+    with ``jnp.where``, never multiplied by a mask: rows past every group
+    may be undefined."""
+    held = pos < n
+    at = jnp.where(held, pos, 0)
+    return [(held[:, j, None], rows[at[:, j]].astype(jnp.float32))
+            for j in range(pos.shape[1])]
+
+
+@jax.custom_vjp
+def dispatch(xt, tok, pos, n):
+    """The sorted buffer: row r holds ``xt[tok[r]]`` for r < n, else 0
+    (rows past ``n`` gather a zero row appended to ``xt``, so no pass
+    over the buffer selects). Its transpose gathers each token's k rows
+    back through ``pos`` and sums them in float32: no scatter over rows."""
+    padded = jnp.concatenate([xt, jnp.zeros((1, xt.shape[1]), xt.dtype)])
+    return padded[jnp.where(jnp.arange(tok.shape[0]) < n, tok, xt.shape[0])]
+
+
+def _dispatch_fwd(xt, tok, pos, n):
+    return dispatch(xt, tok, pos, n), (pos, n)
+
+
+def _dispatch_bwd(res, dxb):
+    pos, n = res
+    dxt = sum(jnp.where(h, r, 0.0) for h, r in _slot_rows(dxb, pos, n))
+    return dxt.astype(dxb.dtype), None, None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(yb, gate, order, pos, n):
+    """float32 (T, D): each token's rows of ``yb`` (through ``pos``),
+    weighted by its gate weights (T, k), token-major. Its transpose is
+    a row gather too: d yb[r] = gate of pair ``order[r]`` times d y of
+    its token, and d gate[t, j] = <d y[t], yb[pos[t, j]]>."""
+    return sum(jnp.where(h, r * gate[:, j, None], 0.0)
+               for j, (h, r) in enumerate(_slot_rows(yb, pos, n)))
+
+
+def _combine_fwd(yb, gate, order, pos, n):
+    return combine(yb, gate, order, pos, n), (yb, gate, order, pos, n)
+
+
+def _combine_bwd(res, dy):
+    yb, gate, order, pos, n = res
+    k = gate.shape[1]
+    rows = (jnp.arange(order.shape[0]) < n)[:, None]
+    w = gate.reshape(-1)[order][:, None]
+    dyb = jnp.where(rows, w * dy[order // k], 0).astype(yb.dtype)
+    dgate = jnp.concatenate([jnp.where(h, jnp.sum(dy * r, -1, keepdims=True), 0.0)
+                             for h, r in _slot_rows(yb, pos, n)], axis=1)
+    return dyb, dgate.astype(gate.dtype), None, None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def routed_experts(params, x: jnp.ndarray, cfg: ModelConfig, first: int = 0):
     """The routed experts' part of the layer, for the experts held in
     ``params`` (ids ``first`` onward). x: (B, S, D) -> float32 (B, S, D).
@@ -487,32 +566,27 @@ def routed_experts(params, x: jnp.ndarray, cfg: ModelConfig, first: int = 0):
     The (token, slot) pairs routed to held experts are sorted by expert
     into a buffer of B*S*min(k, E_held) rows, the most they can fill, so
     nothing is dropped; the grouped matmuls do no work past each
-    expert's rows, and a weighted float32 scatter-add combines. Pairs
-    routed to experts held elsewhere contribute nothing."""
+    expert's rows. Dispatch and combine are row gathers, each the
+    other's transpose, forward and backward: each token gathers its k
+    rows back and sums them, weighted, in float32. Pairs routed to
+    experts held elsewhere contribute nothing."""
     b, s, d = x.shape
     k, held = cfg.top_k, params["w_up"].shape[1]
     t, m = b * s, b * s * min(cfg.top_k, held)
     xt = x.reshape(t, d)
     with device_scope("moe.route"):
         ids, gate = route(params, xt.astype(jnp.float32), cfg)
-        local = ids.reshape(t * k) - first
-        local = jnp.where((local >= 0) & (local < held), local, held)   # not held: last
-        order = jnp.argsort(local, stable=True)[:m]
-        sizes = jax.ops.segment_sum(jnp.ones_like(local), local, held)  # drops "not held"
-        valid = (jnp.arange(m) < sizes.sum())[:, None]
-        tok = order // k
-        xb = jnp.where(valid, xt[tok], 0)
+        order, pos, sizes = sort_pairs(ids, held, first)
+        order, n = order[:m], sizes.sum()
+        xb = dispatch(xt, order // k, pos, n)
     with device_scope("moe.experts"):
         up = ops.grouped_matmul(xb, jnp.moveaxis(params["w_up"], 1, 0), sizes)
         yb = ops.grouped_matmul(jnp.square(jax.nn.relu(up)),
                                 jnp.moveaxis(params["w_down"], 1, 0), sizes)
     with device_scope("moe.route"):
         # Rows past every group are undefined (the kernel leaves them
-        # unwritten): mask them before they meet the gate weights, whose
-        # gradient would otherwise read them.
-        w = gate.reshape(t * k)[order][:, None]
-        contrib = jnp.where(valid, yb, 0).astype(jnp.float32) * w
-        y = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib)
+        # unwritten): the combine selects rows, so they meet no weight.
+        y = combine(yb, gate, order, pos, n)
     return y.reshape(b, s, d)
 
 

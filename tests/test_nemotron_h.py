@@ -108,6 +108,48 @@ def test_undefined_rows_stay_out_of_the_layer(monkeypatch):
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
 
 
+@pytest.mark.parametrize("k,held,first", [(3, 4, 0), (4, 2, 0), (2, 3, 2)],
+                         ids=["k-under-held", "k-over-held", "first-2"])
+def test_gather_pair_matches_scatter_add(k, held, first):
+    """Dispatch and combine of the held experts, row gathers each the
+    other's transpose, against the plain formulation (a row gather in, a
+    float32 ``.at[].add`` out), values and gradients for the tokens, the
+    gate weights and the experts' output rows; the output rows past
+    every group hold NaN and reach nothing."""
+    t, d, n_experts = 16, 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    _, ids = jax.lax.top_k(jax.random.uniform(ks[0], (t, n_experts)), k)
+    m = t * min(k, held)
+    order, pos, sizes = L.sort_pairs(ids, held, first)
+    order, n = order[:m], sizes.sum()
+    np.testing.assert_array_equal(pos.reshape(-1)[order], np.arange(m))
+    assert 0 < int(n) < t * k
+    rows = (jnp.arange(m) < n)[:, None]
+    x = jax.random.normal(ks[1], (t, d))
+    gate = jax.random.uniform(ks[2], (t, k))
+    yb = jnp.where(rows, jax.random.normal(ks[3], (m, d)), jnp.nan)
+    rx, ry = jax.random.normal(ks[4], (m, d)), jax.random.normal(ks[5], (t, d))
+
+    def pair(x, gate, yb):
+        return L.dispatch(x, order // k, pos, n), L.combine(yb, gate, order, pos, n)
+
+    def plain(x, gate, yb):
+        tok = order // k
+        contrib = jnp.where(rows, yb, 0) * gate.reshape(-1)[order][:, None]
+        return (jnp.where(rows, x[tok], 0),
+                jnp.zeros((t, d), jnp.float32).at[tok].add(contrib))
+
+    loss = lambda f: lambda *a: sum(jnp.sum(jnp.sin(o) * r) for o, r in zip(f(*a), (rx, ry)))
+    for got, want in zip(pair(x, gate, yb), plain(x, gate, yb)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    got = jax.grad(loss(pair), argnums=(0, 1, 2))(x, gate, yb)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(x, gate, yb)
+    for name, g, w in zip(("x", "gate", "yb"), got, want):
+        assert g.dtype == w.dtype, name
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6, err_msg=name)
+
+
 def test_grouped_matmul_kernel_matches_ragged_dot():
     """The Pallas grouped matmul (interpreted) against XLA's ragged_dot,
     forward and backward, over groups of uneven, empty and partial-tile

@@ -170,3 +170,28 @@ def test_grouped_matmul_compiles_nemotron(one_chip, monkeypatch, k, n):
     assert kernels and all("gmm" in name for name in kernels)
     assert any("tgmm" in name for name in kernels)
     assert "ragged-dot" not in hlo
+
+
+def test_expert_layer_gathers_rows_nemotron(one_chip, monkeypatch):
+    """The held experts' layer at Nemotron 3 Nano's widths (2 x 2048
+    tokens, d 2688, 8 of 128 experts held, top 6), forward and backward:
+    the megablox kernels, and no scatter whose result has rows of width
+    2688, since dispatch and combine are row gathers both ways. Scatters
+    of scalars (the kernels' own, the top-k's, the sorted positions)
+    stay."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import layers as L
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("nemotron3-nano-30b-a3b"), experts_held=8)
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(lambda k: L.experts_init(k, cfg),
+                                         jax.random.PRNGKey(0)))
+    x = _spec(one_chip, (2, 2048, cfg.d_model))
+    loss = lambda p, x: jnp.sum(jnp.square(L.routed_experts(p, x, cfg)))
+    hlo = _hlo(jax.value_and_grad(loss, argnums=(0, 1)), params, x)
+    assert "tpu_custom_call" in hlo
+    scatters = [line.split(" scatter(")[0] for line in hlo.splitlines() if " scatter(" in line]
+    assert scatters
+    assert not [s for s in scatters if re.search(r"\[(\d+,)*2688\]", s)]
