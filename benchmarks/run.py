@@ -93,11 +93,10 @@ def main() -> None:
             raise SystemExit(1)
         return
 
-    from benchmarks.lm_steps import ALL_LM
     from benchmarks.paper_figs import ALL_FIGS
     from benchmarks.roofline import ALL_ROOFLINE
 
-    suites = {**ALL_FIGS, **ALL_LM, **ALL_ROOFLINE}
+    suites = {**ALL_FIGS, **ALL_ROOFLINE}
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

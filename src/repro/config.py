@@ -329,7 +329,6 @@ MULTI_POD = MeshSpec((2, 16, 16), ("pod", "data", "model"))
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class HapiConfig:
-    enabled: bool = True
     # Splitting algorithm (paper §5.4): C = bandwidth * window_s.
     network_bandwidth: float = 1e9 / 8        # bytes/s (paper default: 1 Gbps)
     window_s: float = 1.0
@@ -338,13 +337,8 @@ class HapiConfig:
     cos_batch_min: int = 32                   # b_r_min (paper: 25; TPU: sublane-friendly)
     cos_hbm_budget: float = HW.hbm_capacity   # per-chip budget on the storage pod
     memory_headroom: float = 0.08             # over-estimation discipline (paper §5.3)
-    # POST request granularity (paper: 1000 images per request).
-    request_size: int = 1024                  # samples per POST request
     # Beyond-paper: compress split activations crossing the tier boundary.
     compress_transfer: bool = False           # int8 per-tile quantization
-    # Beyond-paper: restrict split candidates to block boundaries that are
-    # already collective-free under the TP sharding (always on; documented).
-    collective_aware: bool = True
 
 
 # ---------------------------------------------------------------------------
@@ -361,9 +355,7 @@ class TrainConfig:
     warmup_steps: int = 100
     total_steps: int = 1000
     microbatch: int = 0                       # 0 -> whole per-device batch at once
-    remat: str = "block"                      # none | block | full
     opt_state_dtype: str = "float32"          # grok overrides to bfloat16
-    zero_sharding: bool = True                # shard optimizer states over data axis
     seed: int = 0
 
 

@@ -17,9 +17,9 @@ Beyond-paper extensions (recorded separately in EXPERIMENTS.md §Perf):
     equal the bytes a compressed split actually puts on the trunk.
   * ``cost_optimal``  — pick argmin of the §4 cost model over all
     boundaries instead of the paper's threshold heuristic.
-  * ``collective_aware`` — candidates are restricted to block boundaries
-    (always true by construction here: boundaries ARE block boundaries, so
-    the tier transfer never splits a TP all-reduce pair).
+
+Split candidates are block boundaries by construction, so the tier
+transfer never splits a TP all-reduce pair.
 """
 from __future__ import annotations
 

@@ -1,28 +1,21 @@
 """Public wrappers for the Pallas kernels, with backend dispatch.
 
-The int8 boundary pair (``quantize_int8`` / ``dequantize_int8``), the
-SSD scan and the grouped matmul of the expert layer follow the platform:
-on TPU they run the compiled Pallas kernels, elsewhere the pure-XLA
-references (the SSD scan and the grouped matmul also where their blocks
-cannot tile the input). Flash and decode attention sit off the
-model path; ``use_pallas(True)`` routes them to their Pallas kernels, and
-``use_pallas(True, interpret=True)`` runs those kernels in the Pallas
-interpreter off-TPU (tests only). Every wrapper reads the switch when it
-is called, so a toggle takes effect on the next call.
+Every wrapper follows the platform: the int8 boundary pair
+(``quantize_int8`` / ``dequantize_int8``), the SSD scan and the grouped
+matmul of the expert layer run the compiled Pallas kernels on TPU and the
+pure-XLA references elsewhere (the SSD scan and the grouped matmul also
+where their blocks cannot tile the input). Off the model path, the flash
+and decode attention kernels are called directly from their modules.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import decode_attention as dk
-from repro.kernels import flash_attention as fk
 from repro.kernels import int8_transfer as ik
 from repro.kernels import ref
 from repro.kernels import ssd_scan as sk
 from repro.obs import device_scope
-
-_STATE = {"pallas": False, "interpret": False}
 
 # ---------------------------------------------------------------------------
 # The authoritative int8 wire-compression ratio.
@@ -64,44 +57,8 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def use_pallas(enable: bool = True, *, interpret: bool = False) -> None:
-    """Route flash and decode attention to their Pallas kernels.
-    ``interpret`` runs them in the Pallas interpreter; it is refused on
-    TPU, where the kernels compile."""
-    if interpret and on_tpu():
-        raise ValueError("Pallas interpret mode is for testing off-TPU")
-    _STATE["pallas"] = enable
-    _STATE["interpret"] = interpret
-
-
-def pallas_enabled() -> bool:
-    return _STATE["pallas"]
-
-
-_ref_flash = jax.jit(ref.flash_attention,
-                     static_argnames=("causal", "window", "softcap"))
-_ref_decode = jax.jit(ref.decode_attention, static_argnames=("softcap",))
 _ref_quantize = jax.jit(ref.quantize_int8, static_argnames=("tile",))
 _ref_dequantize = jax.jit(ref.dequantize_int8, static_argnames=("dtype",))
-
-
-# ---------------------------------------------------------------------------
-def flash_attention(q, k, v, *, causal=True, window=None, softcap=None):
-    if _STATE["pallas"]:
-        return fk.flash_attention_pallas(
-            q, k, v, causal=causal, window=window, softcap=softcap,
-            interpret=_STATE["interpret"],
-        )
-    return _ref_flash(q, k, v, causal=causal, window=window, softcap=softcap)
-
-
-def decode_attention(q, k_cache, v_cache, length, *, softcap=None):
-    if _STATE["pallas"]:
-        return dk.decode_attention_pallas(
-            q, k_cache, v_cache, length, softcap=softcap,
-            interpret=_STATE["interpret"],
-        )
-    return _ref_decode(q, k_cache, v_cache, length, softcap=softcap)
 
 
 def ssd_scan(x, dtA, dt, B_, C_, D, init_state=None, *, chunk: int = 256):

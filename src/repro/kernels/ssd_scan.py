@@ -56,7 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 1024            # target rows (heads x P) of a head block
-MAX_STATE_BYTES = 8 << 20    # f32 state of one sequence, held in VMEM
+STATE_VMEM_BYTES = 8 << 20   # f32 state of one sequence, held in VMEM
 VMEM_LIMIT = 64 << 20
 
 
@@ -88,7 +88,7 @@ def plan_blocks(x_shape, n: int, chunk: int, interpret: bool = False,
     g = min(h, max(1, LANES // p))
     if h % g or not (tiled(g * p, LANES, h * p) and tiled(p, 16, h * p)):
         return None
-    if h * n * p * 4 > MAX_STATE_BYTES:
+    if h * n * p * 4 > STATE_VMEM_BYTES:
         return None
     blocks = [d for d in range(g, h + 1, g) if h % d == 0 and tiled(d, 8, h)]
     fit = [d for d in blocks if d * p <= BLOCK_ROWS]

@@ -59,7 +59,6 @@ from repro.launch import mesh as meshlib
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.specs import decode_specs, input_specs, param_specs
 from repro.models.api import build_model
-from repro.models.module import remat_override
 from repro.models.transformer import Model
 from repro.optim.adamw import OptState
 from repro.train.steps import (
@@ -81,14 +80,11 @@ def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float) -> Dict[st
 
 
 # ---------------------------------------------------------------------------
-# Per-arch perf configs (EXPERIMENTS.md §Perf hillclimb results).
-# --baseline disables these for the paper-faithful reference lowering.
+# Per-arch perf configs, applied with --perf.
 # ---------------------------------------------------------------------------
-# Only overrides that *won* their A/B (EXPERIMENTS.md §Perf): TP-only for
-# the MoE arch whose FSDP gathers dominated; coarse extraction + fine
-# accumulation for the 314B giant. Everything else benefits from the
-# code-level fixes (MoE buffer constraints, flash-decode cache sharding)
-# that apply to baseline and perf configs alike after I1/I3.
+# Only overrides that *won* their A/B: TP-only for the MoE arch whose FSDP
+# gathers dominated; coarse extraction + fine accumulation for the 314B
+# giant. Every other arch lowers with the defaults either way.
 PERF_OVERRIDES = {
     "moonshot-v1-16b-a3b": {"train": {"fsdp": False}, "prefill": {"fsdp": False}},
     "whisper-small": {"train": {"fsdp": False}},
@@ -138,7 +134,6 @@ def lower_cell(
     microbatch_div: int = 8,
     donate: bool = True,
     cfg_override=None,
-    remat: str = "block",
     cos_batch: int = 0,
 ) -> Dict[str, Any]:
     cfg = cfg_override or get_config(arch)
@@ -167,7 +162,7 @@ def lower_cell(
                 cos_batch=max(1, micro // sh0.data_size),
             )
         plan = plan_for_mesh(cfg, shape, hapi, ms)
-        tc = TrainConfig(microbatch=micro, remat=remat,
+        tc = TrainConfig(microbatch=micro,
                          opt_state_dtype="bfloat16" if "grok" in arch else "float32")
         rc = RunConfig(model=cfg, shape=shape, hapi=hapi, train=tc)
         pspec = param_specs(model)
@@ -219,8 +214,7 @@ def lower_cell(
             ),
             donate_argnums=(0,) if donate else (),
         )
-        with mesh, activation_sharding(dp, model_size=ms.axis_size('model')), \
-                remat_override(remat):
+        with mesh, activation_sharding(dp, model_size=ms.axis_size('model')):
             lowered = jf.lower(state_s, batch_s)
         extra = {"split": plan.split, "cos_batch": plan.cos_batch,
                  "microbatch": micro, "n_blocks": cfg.n_blocks}
@@ -343,13 +337,10 @@ def main(argv=None) -> int:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--no-fsdp", action="store_true")
     ap.add_argument("--compress", action="store_true")
-    ap.add_argument("--remat", default="block")
     ap.add_argument("--microbatch-div", type=int, default=8)
     ap.add_argument("--cos-batch", type=int, default=0)
-    ap.add_argument("--baseline", action="store_true",
-                    help="paper-faithful defaults (no per-arch perf overrides)")
     ap.add_argument("--perf", action="store_true",
-                    help="apply PERF_OVERRIDES (EXPERIMENTS.md §Perf)")
+                    help="apply PERF_OVERRIDES")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
 
@@ -365,8 +356,7 @@ def main(argv=None) -> int:
     for arch, shape_name in cells:
         try:
             kw = dict(fsdp=not args.no_fsdp, compress=args.compress,
-                      remat=args.remat, microbatch_div=args.microbatch_div,
-                      cos_batch=args.cos_batch)
+                      microbatch_div=args.microbatch_div, cos_batch=args.cos_batch)
             if args.perf:
                 kw.update(perf_overrides(arch, SHAPES[shape_name].kind))
             r = lower_cell(arch, shape_name, multi_pod=args.multi_pod, **kw)

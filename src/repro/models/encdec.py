@@ -3,7 +3,7 @@
 The conv/mel frontend is a STUB per the assignment: ``input_specs()``
 provides pre-computed frame embeddings of shape (B, S_frames, d_model).
 
-Hapi mapping (DESIGN.md §4): the TL feature-extraction prefix is the
+Hapi mapping: the TL feature-extraction prefix is the
 *encoder* — its blocks are the split candidates; the trainable part is the
 remaining encoder blocks + the decoder. Decode shapes exercise the decoder
 with a self-attention KV cache of ``seq_len`` plus a cross-attention cache
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.distributed.autoshard import constrain_act, constrain_logits
 from repro.models import layers as L
-from repro.models.module import dtype_of, embed_init, maybe_remat, slice_stack, stack_init
+from repro.models.module import dtype_of, embed_init, remat, slice_stack, stack_init
 from repro.models.transformer import Model, cross_entropy
 
 CROSS_ATTN_FRAMES = 1500  # whisper 30s window
@@ -109,8 +109,6 @@ def dec_block_decode(bp, h, self_cache, enc_kv, pos, cfg: ModelConfig):
 # Model
 # ---------------------------------------------------------------------------
 def build_encdec(cfg: ModelConfig) -> Model:
-    remat_name = "block"
-
     def init(key):
         k1, k2, k3, k4 = jax.random.split(key, 4)
         dt = dtype_of(cfg.param_dtype)
@@ -124,9 +122,7 @@ def build_encdec(cfg: ModelConfig) -> Model:
         }
 
     def _encode_from(blocks, h, positions):
-        body = maybe_remat(
-            lambda hh, bp: (enc_block_apply(bp, hh, cfg, positions), None), remat_name
-        )
+        body = remat(lambda hh, bp: (enc_block_apply(bp, hh, cfg, positions), None))
         h, _ = jax.lax.scan(body, h, blocks)
         return h
 
@@ -140,7 +136,7 @@ def build_encdec(cfg: ModelConfig) -> Model:
             kv = cross_kv(bp["cross_attn"], enc_out, cfg)
             return dec_block_apply(bp, hh, kv, cfg, positions), None
 
-        body = maybe_remat(body, remat_name)
+        body = remat(body)
         h, _ = jax.lax.scan(body, h, params["dec_blocks"])
         h = L.layernorm(params["dec_norm"], h, cfg.norm_eps)
         logits = jnp.einsum(

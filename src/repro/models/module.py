@@ -91,41 +91,10 @@ def tree_param_count(tree) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Remat policies
+# Rematerialization
 # ---------------------------------------------------------------------------
-_REMAT = {"policy": None}
-
-
-import contextlib
-
-
-@contextlib.contextmanager
-def remat_override(name):
-    """Override the models' remat policy (hillclimb knob; None = default)."""
-    prev = _REMAT["policy"]
-    _REMAT["policy"] = name
-    try:
-        yield
-    finally:
-        _REMAT["policy"] = prev
-
-
-def current_remat(default: str) -> str:
-    return _REMAT["policy"] or default
-
-
-def remat_policy(name: str):
-    if name == "none":
-        return None
-    if name == "block":
-        return jax.checkpoint_policies.nothing_saveable
-    if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    raise ValueError(name)
-
-
-def maybe_remat(fn, policy_name: str):
-    policy_name = current_remat(policy_name)
-    if policy_name == "none":
-        return fn
-    return jax.checkpoint(fn, policy=remat_policy(policy_name), prevent_cse=False)
+def remat(fn):
+    """``fn`` checkpointed with nothing saved: its backward recomputes
+    the forward of each scanned block."""
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable,
+                          prevent_cse=False)

@@ -1,6 +1,6 @@
 """Decoder-only LM covering the dense / MoE / SSM / hybrid / VLM families.
 
-Structure (DESIGN.md §3):
+Structure:
   * The layer stack is organized into *blocks* — the scan units — whose
     boundaries are the Hapi split candidates ("for DNNs structured as a
     sequence of blocks we split at block boundary", paper Table 1).
@@ -26,7 +26,7 @@ from repro.config import ModelConfig
 from repro.distributed.autoshard import constrain_act, constrain_logits
 from repro.models import layers as L
 from repro.models import ssm as S
-from repro.models.module import dtype_of, embed_init, maybe_remat, slice_stack, stack_init
+from repro.models.module import dtype_of, embed_init, remat, slice_stack, stack_init
 
 
 # ---------------------------------------------------------------------------
@@ -264,8 +264,6 @@ def cross_entropy(logits, labels, mask=None):
 
 def build_lm(cfg: ModelConfig) -> Model:
     """Decoder LM for families dense/moe/ssm/hybrid/vlm and layer patterns."""
-    remat_name = "block"
-
     def init(key):
         k1, k2, k3 = jax.random.split(key, 3)
         dt = dtype_of(cfg.param_dtype)
@@ -280,9 +278,8 @@ def build_lm(cfg: ModelConfig) -> Model:
             params["unembed"] = embed_init(k3, cfg.padded_vocab, cfg.d_model, dt)
         return params
 
-    def _scan_blocks(stacked, h, positions, remat=remat_name):
-        body = lambda hh, bp: (block_apply(bp, hh, cfg, positions), None)
-        body = maybe_remat(body, remat)
+    def _scan_blocks(stacked, h, positions):
+        body = remat(lambda hh, bp: (block_apply(bp, hh, cfg, positions), None))
         h, _ = jax.lax.scan(body, h, stacked)
         return h
 

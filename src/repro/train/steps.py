@@ -49,6 +49,45 @@ def _tree_chunk(tree, n_chunks: int):
     )
 
 
+def _micro_chunks(acts, batch, micro: int):
+    """``(n_chunks, (acts, batch) split into chunks of ``micro`` rows)``."""
+    n_chunks = max(1, next(iter(batch.values())).shape[0] // micro)
+    return n_chunks, (_tree_chunk(acts, n_chunks), _tree_chunk(batch, n_chunks))
+
+
+def _accumulator(tune: Callable, trainable, get_acts: Callable,
+                 constrain: Optional[Callable]) -> Callable:
+    """``accumulate(xs) -> (grads, loss_sum)``: ``tune``'s loss and its
+    gradient in ``trainable``, summed in f32 over a scan of ``xs``, where
+    ``get_acts`` maps one element of ``xs`` to ``(acts, batch_chunk)``.
+    The zero tree is traced here, so a step calls this where its program
+    builds that tree."""
+    zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), trainable)
+    if constrain:
+        zeros = constrain(zeros, "grads")
+
+    def gstep(carry, x):
+        g_acc, loss_acc = carry
+        acts, bchunk = get_acts(x)
+        loss, g = jax.value_and_grad(tune)(trainable, acts, bchunk)
+        with device_scope("hapi.tune"):
+            g_acc = jax.tree.map(lambda a, b: a + b.astype(a.dtype), g_acc, g)
+        if constrain:
+            # Keep the accumulator ZeRO-sharded inside the scan carry.
+            g_acc = constrain(g_acc, "grads")
+        return (g_acc, loss_acc + loss), None
+
+    return lambda xs: jax.lax.scan(gstep, (zeros, 0.0), xs)[0]
+
+
+def _update(trainable, opt: OptState, grads, loss_sum, n_chunks: int, tc):
+    """AdamW on the mean gradient over the chunks: ``(trainable, opt, metrics)``."""
+    with device_scope("hapi.adamw"):
+        grads = jax.tree.map(lambda g: g / n_chunks, grads)
+    new_trainable, new_opt, om = adamw_update(trainable, grads, opt, tc)
+    return new_trainable, new_opt, {"loss": loss_sum / n_chunks, **om}
+
+
 def build_hapi_train_step(
     model: Model,
     rc: RunConfig,
@@ -61,72 +100,38 @@ def build_hapi_train_step(
     tune = make_tune_loss_fn(model, plan)
     tc = rc.train
 
+    def constrain_acts(acts):
+        return constrain(acts, "acts") if constrain else acts
+
     def train_step(state: TrainState, batch):
         b = next(iter(batch.values())).shape[0]
         cos_b = min(plan.cos_batch, b)          # §5.5: the adapted COS batch
         micro = min(tc.microbatch or b, b)      # grad-accumulation chunk
-
-        def gstep_factory(get_acts):
-            def gstep(carry, bt):
-                g_acc, loss_acc = carry
-                acts, bchunk = get_acts(bt)
-                loss, g = jax.value_and_grad(tune)(state.trainable, acts, bchunk)
-                with device_scope("hapi.tune"):
-                    g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
-                if constrain:
-                    # Keep the accumulator ZeRO-sharded inside the scan carry.
-                    g_acc = constrain(g_acc, "grads")
-                return (g_acc, loss_acc + loss), None
-            return gstep
-
-        zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), state.trainable)
-        if constrain:
-            zeros = constrain(zeros, "grads")
+        extract = make_extract_fn(model, TierPlan(
+            plan.split, cos_b, plan.compress, plan.decision))
 
         if cos_b <= micro:
             # Fused path: extract chunk -> grad on chunk -> accumulate. One
             # chunk's boundary activations live at a time.
+            accumulate = _accumulator(
+                tune, state.trainable,
+                lambda bt: (constrain_acts(extract(state.frozen, bt)), bt), constrain)
             n_chunks = max(1, b // cos_b)
-            batch_c = _tree_chunk(batch, n_chunks)
-            one = TierPlan(plan.split, cos_b, plan.compress, plan.decision)
-            extract_one = make_extract_fn(model, one)
-
-            def get_acts(bt):
-                acts = extract_one(state.frozen, bt)
-                if constrain:
-                    acts = constrain(acts, "acts")
-                return acts, bt
-
-            (grads, loss_sum), _ = jax.lax.scan(
-                gstep_factory(get_acts), (zeros, 0.0), batch_c)
+            grads, loss_sum = accumulate(_tree_chunk(batch, n_chunks))
         else:
             # Coarse-extraction path (batch adaptation granted a big COS
             # batch): run feature extraction at cos_b — the frozen-prefix
             # weights are (FSDP-)gathered cos_b/micro times *fewer* — then
-            # grad-accumulate over micro chunks of the stored activations.
-            extract = make_extract_fn(model, TierPlan(
-                plan.split, cos_b, plan.compress, plan.decision))
-            acts = extract(state.frozen, batch)
-            if constrain:
-                acts = constrain(acts, "acts")
-            n_chunks = max(1, b // micro)
-            acts_c = _tree_chunk(acts, n_chunks)
-            batch_c = _tree_chunk(batch, n_chunks)
+            # the tier split's tune step over micro chunks of the activations.
+            accumulate = _accumulator(
+                tune, state.trainable, lambda ab: (constrain_acts(ab[0]), ab[1]), constrain)
+            acts = constrain_acts(extract(state.frozen, batch))
+            n_chunks, chunks = _micro_chunks(acts, batch, micro)
+            grads, loss_sum = accumulate(chunks)
 
-            def get_acts(bt):
-                a, bchunk = bt
-                if constrain:
-                    a = constrain(a, "acts")
-                return a, bchunk
-
-            (grads, loss_sum), _ = jax.lax.scan(
-                gstep_factory(get_acts), (zeros, 0.0), (acts_c, batch_c))
-
-        with device_scope("hapi.adamw"):
-            grads = jax.tree.map(lambda g: g / n_chunks, grads)
-        new_trainable, new_opt, om = adamw_update(state.trainable, grads, state.opt, tc)
-        metrics = {"loss": loss_sum / n_chunks, **om}
-        return TrainState(state.frozen, new_trainable, new_opt), metrics
+        trainable, opt, metrics = _update(state.trainable, state.opt, grads, loss_sum,
+                                          n_chunks, tc)
+        return TrainState(state.frozen, trainable, opt), metrics
 
     return train_step
 
@@ -152,7 +157,8 @@ def build_tier_steps(model: Model, rc: RunConfig, plan: TierPlan,
                      *, constrain: Optional[Callable] = None):
     """The two-program tier split (paper Fig. 8): ``extract_step`` runs on
     the storage mesh (COS), ``tune_step`` on the compute mesh; the returned
-    activations cross the inter-pod link (optionally int8, DESIGN.md §2).
+    activations cross the inter-pod link (optionally int8). ``tune_step``
+    is the coarse path of ``build_hapi_train_step`` after its extract.
     """
     tc = rc.train
     extract = make_extract_fn(model, plan)
@@ -163,29 +169,9 @@ def build_tier_steps(model: Model, rc: RunConfig, plan: TierPlan,
 
     def tune_step(trainable, opt, acts, batch):
         b = next(iter(batch.values())).shape[0]
-        micro = min(tc.microbatch or b, b)
-        n_chunks = max(1, b // micro)
-        acts_c = _tree_chunk(acts, n_chunks)
-        batch_c = _tree_chunk(batch, n_chunks)
-
-        def gstep(carry, chunk):
-            g_acc, loss_acc = carry
-            a, bt = chunk
-            loss, g = jax.value_and_grad(tune)(trainable, a, bt)
-            with device_scope("hapi.tune"):
-                g_acc = jax.tree.map(lambda x, y: x + y.astype(x.dtype), g_acc, g)
-            if constrain:
-                g_acc = constrain(g_acc, "grads")
-            return (g_acc, loss_acc + loss), None
-
-        zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), trainable)
-        if constrain:
-            zeros = constrain(zeros, "grads")
-        (grads, loss_sum), _ = jax.lax.scan(gstep, (zeros, 0.0), (acts_c, batch_c))
-        with device_scope("hapi.adamw"):
-            grads = jax.tree.map(lambda g: g / n_chunks, grads)
-        new_trainable, new_opt, om = adamw_update(trainable, grads, opt, tc)
-        return new_trainable, new_opt, {"loss": loss_sum / n_chunks, **om}
+        n_chunks, chunks = _micro_chunks(acts, batch, min(tc.microbatch or b, b))
+        grads, loss_sum = _accumulator(tune, trainable, lambda ab: ab, constrain)(chunks)
+        return _update(trainable, opt, grads, loss_sum, n_chunks, tc)
 
     return extract_step, tune_step
 
@@ -208,12 +194,3 @@ def build_decode_step(model: Model) -> Callable:
 
     return serve_step
 
-
-def build_forward_step(model: Model) -> Callable:
-    """Pure forward to logits (prefill-shaped lowering for encoder-style
-    cells where the KV cache is not meaningful)."""
-
-    def fwd(params, batch):
-        return model.forward(params, batch)
-
-    return fwd

@@ -322,27 +322,3 @@ def test_int8_wire_savings():
     q, s = ref.quantize_int8(x)
     wire = q.size * q.dtype.itemsize + s.size * s.dtype.itemsize
     assert wire < 0.6 * x.size * x.dtype.itemsize
-
-
-def test_ops_dispatch_pallas_toggle():
-    """The toggle is read on every call: switched on, ops.flash_attention
-    traces the Pallas kernel; switched off, the XLA reference. Both agree
-    with the interpreted kernel and with ref.flash_attention."""
-    from repro.kernels import ops
-
-    x = jax.random.normal(KEY, (2, 64, 4, 32))
-    trace = lambda: str(jax.make_jaxpr(
-        lambda a: ops.flash_attention(a, a, a, causal=True))(x))
-    try:
-        ops.use_pallas(True, interpret=True)
-        assert "pallas_call" in trace()
-        o_on = ops.flash_attention(x, x, x, causal=True)
-    finally:
-        ops.use_pallas(False)
-    assert "pallas_call" not in trace()
-    o_off = ops.flash_attention(x, x, x, causal=True)
-    kernel = flash_attention_pallas(x, x, x, causal=True, interpret=True)
-    exp = ref.flash_attention(x, x, x, causal=True)
-    for out in (o_on, o_off, kernel):
-        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                                   atol=2e-5, rtol=2e-5)

@@ -192,13 +192,6 @@ def test_ops_int8_on_tpu_rejects_unaligned_width(monkeypatch):
                             jnp.ones((4, 3), jnp.float32))
 
 
-def test_use_pallas_refuses_interpret_on_tpu(monkeypatch):
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    with pytest.raises(ValueError, match="off-TPU"):
-        ops.use_pallas(True, interpret=True)
-    assert not ops.pallas_enabled()
-
-
 # ---------------------------------------------------------------------------
 # End to end: compression buys back pushdown under contention
 # ---------------------------------------------------------------------------
